@@ -28,7 +28,8 @@ from tpuminter import chain
 from tpuminter.ops import scrypt as scrypt_ops
 from tpuminter.ops import sha256 as ops
 from tpuminter.protocol import PowMode, Request, Result
-from tpuminter.search import pipeline_spans
+from tpuminter.search import pipeline_spans, pull
+from tpuminter.spans import DISPATCH, RESOLVE, span
 from tpuminter.worker import Miner
 
 __all__ = ["JaxMiner"]
@@ -190,10 +191,13 @@ class JaxMiner(Miner):
         best: Optional[Tuple[int, int]] = None  # (hash, nonce)
         for start, valid, nonces in self._batches(req.lower, req.upper):
             batch = jnp.asarray(nonces.astype(np.uint32))
-            found, first, midx, min_digest, first_digest = _target_step(
-                template, batch, target_words
-            )
-            if bool(found):
+            with span(DISPATCH):
+                found, first, midx, min_digest, first_digest = _target_step(
+                    template, batch, target_words
+                )
+            with span(RESOLVE):
+                found = bool(found)
+            if found:
                 first = int(first)
                 nonce = int(nonces[first])
                 h = ops.digest_to_int(np.asarray(first_digest))
@@ -275,7 +279,7 @@ class JaxMiner(Miner):
         for (_, base_g, valid, nonces), handle in pipeline_spans(
             spans(), dispatch, depth=self.depth
         ):
-            row = np.asarray(handle)
+            row = pull(handle)
             if int(row[0]):
                 first = int(row[1])
                 g = base_g | int(nonces[first])

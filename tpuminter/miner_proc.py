@@ -20,6 +20,7 @@ traceback)``.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import logging
 import multiprocessing
@@ -28,7 +29,8 @@ import signal
 import traceback
 from typing import Callable, Iterator, Optional
 
-from tpuminter.protocol import Request, Result
+from tpuminter.protocol import PowMode, Request, Result
+from tpuminter.spans import AWAIT_CHUNK, CANCEL, WINNER, span
 from tpuminter.worker import Miner, ProfiledMiner, _build_miner
 
 __all__ = ["ProcessMiner", "device_miner"]
@@ -161,7 +163,8 @@ def _serve(conn, factory, args, kwargs) -> None:
     conn.send(("ready", miner.backend, miner.lanes, miner.span))
     while True:
         try:
-            cmd = conn.recv()
+            with span(AWAIT_CHUNK):
+                cmd = conn.recv()
         except EOFError:
             return
         if cmd[0] == "stop":
@@ -193,7 +196,8 @@ def _run_job(conn, miner: Miner, kind: str, request: Request, progress: bool) ->
         gen = miner.compute(request) if kind == "compute" else miner.mine(request)
         for item in gen:
             if item is not None:
-                conn.send(("result", item))
+                with _result_span(request, item):
+                    conn.send(("result", item))
                 return True
             if conn.poll():
                 try:
@@ -202,6 +206,11 @@ def _run_job(conn, miner: Miner, kind: str, request: Request, progress: bool) ->
                     return False
                 # a cancel, or the session ending (close) or the
                 # worker ending (stop): each abandons the job
+                if cmd[0] == "cancel":
+                    with span(CANCEL, job=request.job_id, chunk=request.chunk_id):
+                        gen.close()
+                        conn.send(("end",))
+                    return True
                 gen.close()
                 if cmd[0] == "close":
                     _close(miner)
@@ -215,6 +224,15 @@ def _run_job(conn, miner: Miner, kind: str, request: Request, progress: bool) ->
         if gen is not None:
             gen.close()
     return True
+
+
+def _result_span(request: Request, item):
+    """A winner span around sending a Result that answers its whole
+    job (a found TARGET or SCRYPT winner; every MIN chunk is ``found``
+    and answers nothing), else no span."""
+    if isinstance(item, Result) and item.found and item.mode != PowMode.MIN:
+        return span(WINNER, job=request.job_id, chunk=request.chunk_id)
+    return contextlib.nullcontext()
 
 
 def device_miner(

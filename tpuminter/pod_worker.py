@@ -67,6 +67,7 @@ from tpuminter.search import (
     CandidateSearch,
     pack_handle,
     pipeline_spans,
+    pull,
     resolve_handle,
 )
 from tpuminter.worker import Miner
@@ -572,7 +573,7 @@ class PodMiner(Miner):
         for start, handle in pipeline_spans(
             starts, lambda s: sweep(jnp.uint32(s)), depth=self.depth
         ):
-            row = np.asarray(handle)  # one pull: [found, win, words×8, min]
+            row = pull(handle)  # one pull: [found, win, words×8, min]
             if int(row[0]):
                 nonce = int(row[1])
                 # recompute the winner's hash host-side (one nonce, cheap
@@ -709,7 +710,7 @@ class PodMiner(Miner):
 
         best: Optional[Tuple[int, int]] = None  # (hash, nonce)
         for _, handle in pipeline_spans(starts, dispatch, depth=self.depth):
-            row = np.asarray(handle)
+            row = pull(handle)
             cand = (
                 (int(row[0]) << 32) | int(row[1]),
                 (int(row[2]) << 32) | int(row[3]),
@@ -818,7 +819,7 @@ class PodMiner(Miner):
             for nonce, handle in pipeline_spans(
                 starts, dispatch, depth=self.depth
             ):
-                row = np.asarray(handle)
+                row = pull(handle)
                 if int(row[0]):
                     g = base_g | int(row[1])
                     h = ops.digest_to_int(row[3:11])

@@ -55,7 +55,8 @@ from tpuminter import chain
 from tpuminter.ops import sha256 as ops
 from tpuminter.protocol import MIN_UNTRACKED, Request, Result
 from tpuminter.search import (
-    CandidateSearch, pack_handle, pipeline_spans, resolve_handle, timed_call,
+    CandidateSearch, pack_handle, pipeline_spans, pull, resolve_handle,
+    timed_call,
 )
 
 __all__ = [
@@ -751,7 +752,7 @@ def mine_rolled_tracking(
     starts = range(req.lower, req.upper + 1, window)
     best: Optional[Tuple[int, int]] = None  # (hash, global index)
     for start, handle in pipeline_spans(starts, dispatch, depth=depth):
-        row = np.asarray(handle)
+        row = pull(handle)
         if int(row[0]):
             g = start + int(row[1])
             h = ops.digest_to_int(row[3:11])

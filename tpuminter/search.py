@@ -34,8 +34,10 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Iterable, Iterator, List, Optional, Tuple
 
+from tpuminter.spans import DISPATCH, RESOLVE, span
+
 __all__ = [
-    "CandidateSearch", "SearchOutcome", "pipeline_spans", "timed_call",
+    "CandidateSearch", "SearchOutcome", "pipeline_spans", "pull", "timed_call",
 ]
 
 #: sweep(base, n) -> opaque handle (asynchronous dispatch)
@@ -55,6 +57,15 @@ def pack_handle(found, off):
     import jax.numpy as jnp
 
     return jnp.stack([found, off])
+
+
+def pull(handle):
+    """Block on one device call's result and copy it to the host: the
+    sync point of every pipelined loop, recorded as a resolve span."""
+    import numpy as np
+
+    with span(RESOLVE):
+        return np.asarray(handle)
 
 
 def resolve_handle(handle) -> Tuple[int, int]:
@@ -94,8 +105,8 @@ def pipeline_spans(
     overlaps device compute instead of serializing with it (the same
     0.73 → ≥1.0 GH/s step PERF.md records for the TARGET pipeline).
     ``dispatch(span)`` must be non-blocking (JAX async dispatch is);
-    the caller resolves each yielded handle (``np.asarray``/``int``),
-    which is the only sync point.
+    the caller resolves each yielded handle (:func:`pull`), which is the
+    only sync point. Each dispatch is recorded as a dispatch span.
 
     Early exit: a caller that stops consuming (found a winner,
     Cancel abandoned the generator) simply leaves the in-flight
@@ -107,8 +118,10 @@ def pipeline_spans(
     if depth < 1:
         raise ValueError("depth must be >= 1")
     inflight: deque = deque()
-    for span in spans:
-        inflight.append((span, dispatch(span)))
+    for item in spans:
+        with span(DISPATCH):
+            handle = dispatch(item)
+        inflight.append((item, handle))
         if len(inflight) >= depth:
             yield inflight.popleft()
     while inflight:
@@ -213,7 +226,9 @@ class CandidateSearch:
         # size mid-run costs ~20 s of compile. Sound
         # because the kernel reports the LOWEST candidate offset: a hit
         # past ``end`` (or past 2^32 wrap) proves [start, end] clean.
-        self._inflight.append((start, start + take - 1, self._sweep(start, self.slab)))
+        with span(DISPATCH):
+            handle = self._sweep(start, self.slab)
+        self._inflight.append((start, start + take - 1, handle))
 
     def _unsearched_min(self) -> Optional[int]:
         starts = [s for s, _ in self._pending]
@@ -279,7 +294,8 @@ class CandidateSearch:
                 assert self._try_finish(), "no work left but not finished"
                 return
             start, end, handle = self._inflight.popleft()
-            found, off = self._resolve(handle)
+            with span(RESOLVE):
+                found, off = self._resolve(handle)
             n = end - start + 1
             if not found or off >= n:
                 # clean sweep: no candidate at any offset within the

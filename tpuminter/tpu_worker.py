@@ -52,6 +52,7 @@ from tpuminter.search import (
     CandidateSearch,
     pack_handle,
     pipeline_spans,
+    pull,
     resolve_handle,
 )
 from tpuminter.worker import Miner
@@ -356,7 +357,7 @@ class TpuMiner(Miner):
         for (start, _), handle in pipeline_spans(
             self._slabs(req.lower, req.upper), dispatch, depth=self.depth
         ):
-            row = np.asarray(handle)
+            row = pull(handle)
             cand = ((int(row[0]) << 32) | int(row[1]), start + int(row[2]))
             if best is None or cand < best:
                 best = cand

@@ -297,6 +297,7 @@ class HashCore(Workload):
         size. Early return abandons in-flight handles un-resolved —
         the documented ``pipeline_spans`` contract."""
         from tpuminter.search import pipeline_spans
+        from tpuminter.spans import RESOLVE, span
 
         spans = (
             (g, min(g + sweep.window - 1, hi))
@@ -306,7 +307,9 @@ class HashCore(Workload):
         for (g, e), handle in pipeline_spans(
             spans, lambda s: sweep.dispatch(p.seed, s[0], s[1], p.threshold)
         ):
-            acc = fold.combine(acc, sweep.resolve(handle, g, e))
+            with span(RESOLVE):
+                part = sweep.resolve(handle, g, e)
+            acc = fold.combine(acc, part)
             if fold.is_final(acc):
                 match = acc[0]
                 searched = min(
