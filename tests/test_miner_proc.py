@@ -6,12 +6,16 @@ worker's own process gets it declared dead at the CLI's loss horizon.
 """
 
 import asyncio
+import contextlib
+import dataclasses
 import os
 import signal
 import socket
+import struct
 import subprocess
 import sys
 import textwrap
+import threading
 import time
 
 import pytest
@@ -57,23 +61,31 @@ def _drain(gen):
     raise AssertionError("no Result")
 
 
-@pytest.fixture
-def coordinator():
-    """A coordinator CLI at the worker's default loss horizon, in its
-    own process so the hog cannot stall its epoch clock too."""
+@contextlib.contextmanager
+def _coordinator_cli(*args: str):
     with socket.socket(socket.AF_INET, socket.SOCK_DGRAM) as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
     proc = subprocess.Popen(
-        [sys.executable, "-m", "tpuminter.coordinator", str(port)],
+        [sys.executable, "-m", "tpuminter.coordinator", str(port), *args],
         cwd=ROOT, stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
     )
-    for line in proc.stdout:
-        if "listening on port" in line:
-            break
-    yield port
-    proc.terminate()
-    proc.communicate(timeout=20)
+    try:
+        for line in proc.stdout:
+            if "listening on port" in line:
+                break
+        yield port
+    finally:
+        proc.terminate()
+        proc.communicate(timeout=20)
+
+
+@pytest.fixture
+def coordinator():
+    """A coordinator CLI at the worker's default loss horizon, in its
+    own process so the hog cannot stall its epoch clock too."""
+    with _coordinator_cli() as port:
+        yield port
 
 
 @pytest.mark.parametrize("isolated", [False, True])
@@ -204,3 +216,180 @@ def test_close_keeps_the_child_serving():
     finally:
         pm.shutdown()
     assert not pm._proc.is_alive()
+
+
+class SlowMiner(CpuMiner):
+    """A CPU miner that sleeps ``pause`` seconds in each step and logs
+    ``job chunk time`` (the system-wide monotonic clock) to ``log`` as
+    each step ends."""
+
+    def __init__(self, log: str, pause: float):
+        super().__init__(batch=64)
+        self.log, self.pause = log, pause
+
+    def mine(self, request):
+        for item in super().mine(request):
+            if item is None:
+                time.sleep(self.pause)
+                with open(self.log, "a") as f:
+                    f.write(f"{request.job_id} {request.chunk_id} "
+                            f"{time.monotonic()}\n")
+            yield item
+
+
+def _step_times(log: str, job_id: int) -> list:
+    with open(log) as f:
+        rows = [line.split() for line in f]
+    return [float(t) for job, _, t in rows if int(job) == job_id]
+
+
+@pytest.mark.parametrize("raced", [False, True])
+def test_cancel_from_another_thread(tmp_path, raced):
+    """``cancel()`` from another thread while ``next(gen)`` waits in
+    ``recv``: the child runs at most one more step and the generator
+    ends with no Result. ``raced``: the caller closes the generator
+    after a step that crossed the cancel, as the role loop does; the job
+    then owes one terminal, however many cancels were sent. Either way
+    the next job and a workload chunk answer as in-process miners do."""
+    log = str(tmp_path / "steps")
+    pm = ProcessMiner(SlowMiner, log, pause=0.1)
+    sent = []
+
+    def cancel():
+        sent.append(time.monotonic())
+        pm.cancel()
+
+    try:
+        gen = pm.mine(_toy(1, 1 << 14))  # 255 steps of 0.1 s uncancelled
+        assert next(gen) is None
+        timer = threading.Timer(0.05, cancel)
+        timer.start()
+        if raced:
+            timer.join()
+            gen.close()  # before the child's terminal came back
+            assert pm._owed == 1
+        else:
+            assert all(item is None for item in gen)
+            timer.join()
+            assert pm._owed == 0
+        pm.cancel()  # a second cancel, with no job running: dropped
+        req = _toy(2, 300)
+        assert _drain(pm.mine(req)) == _drain(CpuMiner().mine(req))
+        assert pm._owed == 0
+        work = Request(
+            job_id=3, mode=PowMode.MIN, lower=0, upper=299,
+            data=hc.pack_params("fmin", seed=7, threshold=0, k=3),
+            chunk_id=1, workload="hashcore",
+        )
+        assert _drain(pm.compute(work)) == _drain(CpuMiner().compute(work))
+    finally:
+        pm.shutdown()
+    assert len([t for t in _step_times(log, 1) if t > sent[0]]) <= 1
+
+
+class RecordingProcessMiner(ProcessMiner):
+    """Records when the role loop forwards each cancel."""
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.cancels = []
+
+    def cancel(self) -> None:
+        self.cancels.append(time.monotonic())
+        super().cancel()
+
+
+def _early_winner(job_id: int, chunk: int, batch: int = 64):
+    """A TARGET job over two chunks whose one winner, the job's least
+    hash, lies in the first batch: the first chunk answers the job at
+    once and the coordinator cancels the second, pipelined behind it."""
+    base = chain.GENESIS_HEADER
+    for dt in range(1, 1000):
+        header = dataclasses.replace(base, timestamp=base.timestamp + dt).pack()
+        hashes = [
+            chain.hash_to_int(chain.dsha256(header[:76] + struct.pack("<I", n)))
+            for n in range(2 * chunk)
+        ]
+        w = min(range(2 * chunk), key=hashes.__getitem__)
+        if w < batch:
+            req = Request(job_id, PowMode.TARGET, 0, 2 * chunk - 1,
+                          header=header, target=hashes[w])
+            return req, w
+    raise AssertionError("no header with an early winner")
+
+
+@pytest.mark.parametrize("next_job", [False, True])
+def test_cancel_reaches_the_child_mid_step(tmp_path, next_job):
+    """The role loop forwards a Cancel for the chunk being mined while
+    its step runs: the child runs at most one more step of it, also
+    when no further job ever arrives. ``next_job``: the next job's Setup
+    and Assign, queued right behind the Cancel, are still handled."""
+    log = str(tmp_path / "steps")
+    chunk = 1024  # 16 steps a chunk
+    req1, winner = _early_winner(1, chunk)
+    req2 = _toy(2, 255)
+    want2 = min((chain.toy_hash(req2.data, n), n) for n in range(256))
+    miner = RecordingProcessMiner(SlowMiner, log, pause=0.2)
+
+    async def scenario(port):
+        worker = asyncio.ensure_future(
+            run_miner("127.0.0.1", port, miner, params=FAST)
+        )
+        await asyncio.sleep(0.3)
+        r1 = await asyncio.wait_for(
+            submit("127.0.0.1", port, req1, params=FAST), 30
+        )
+        r2 = None
+        if next_job:
+            r2 = await asyncio.wait_for(
+                submit("127.0.0.1", port, req2, params=FAST), 30
+            )
+        await asyncio.sleep(1.0)  # the child would mine on meanwhile
+        assert not worker.done()
+        worker.cancel()
+        await asyncio.gather(worker, return_exceptions=True)
+        return r1, r2
+
+    try:
+        with _coordinator_cli("--chunk-size", str(chunk)) as port:
+            r1, r2 = asyncio.run(scenario(port))
+    finally:
+        miner.shutdown()
+    assert (r1.found, r1.nonce) == (True, winner)
+    assert len(miner.cancels) == 1
+    assert len([t for t in _step_times(log, 1) if t > miner.cancels[0]]) <= 1
+    if next_job:
+        assert (r2.hash_value, r2.nonce) == want2
+
+
+def test_session_lost_mid_step(tmp_path):
+    """The coordinator dies while a step runs: the role loop cancels the
+    chunk and returns only once that step has, so the miner's pipe is
+    clean for the next session's first job."""
+    log = str(tmp_path / "steps")
+    miner = RecordingProcessMiner(SlowMiner, log, pause=0.2)
+
+    async def scenario():
+        with _coordinator_cli() as port:
+            worker = asyncio.ensure_future(
+                run_miner("127.0.0.1", port, miner, params=FAST)
+            )
+            await asyncio.sleep(0.3)
+            job = asyncio.ensure_future(
+                submit("127.0.0.1", port, _toy(1, 1 << 14), params=FAST)
+            )
+            while not os.path.exists(log):
+                await asyncio.sleep(0.05)
+        # the coordinator is gone: the worker declares it lost mid-chunk
+        await asyncio.wait_for(worker, 30)
+        job.cancel()
+        await asyncio.gather(job, return_exceptions=True)
+
+    try:
+        asyncio.run(scenario())
+        assert len(miner.cancels) == 1
+        req = _toy(2, 300)
+        assert _drain(miner.mine(req)) == _drain(CpuMiner().mine(req))
+    finally:
+        miner.shutdown()
+    assert len([t for t in _step_times(log, 1) if t > miner.cancels[0]]) <= 1

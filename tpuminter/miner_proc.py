@@ -26,6 +26,7 @@ import logging
 import multiprocessing
 import os
 import signal
+import threading
 import traceback
 from typing import Callable, Iterator, Optional
 
@@ -57,6 +58,9 @@ class ProcessMiner(Miner):
         )
         self._proc.start()
         child.close()
+        #: one sender at a time: :meth:`cancel` sends from the role
+        #: loop's thread while a job's step may send from the executor's
+        self._send_lock = threading.Lock()
         #: terminal messages still owed by jobs abandoned mid-mine
         self._owed = 0
         hello = self._recv()
@@ -86,7 +90,7 @@ class ProcessMiner(Miner):
         while self._owed:
             if self._recv()[0] in _TERMINAL:
                 self._owed -= 1
-        self._conn.send((kind, request, self.progress_cb is not None))
+        self._send((kind, request, self.progress_cb is not None))
         done = False
         try:
             while True:
@@ -110,16 +114,32 @@ class ProcessMiner(Miner):
                     raise RuntimeError(f"miner process:\n{msg[1]}")
         finally:
             if not done:
-                # abandoned (Cancel): the child stops at its next yield
-                # point; the next job first reads this one's terminal
-                self._conn.send(("cancel",))
+                # closed before its terminal: the child stops at its
+                # next yield point, and the next job first reads this
+                # one's terminal. After a cancel() whose step raced it
+                # this cancel is a second one, which the child drops
+                # between jobs, so the job still owes one terminal.
+                self._send(("cancel",))
                 self._owed += 1
+
+    def _send(self, cmd: tuple) -> None:
+        with self._send_lock:
+            self._conn.send(cmd)
+
+    def cancel(self) -> None:
+        """Send the child a cancel now, from any thread: a step blocked
+        in ``recv`` on the executor's thread then ends with ``("end",)``,
+        or with a ``("step",)`` that crossed it (the pipe's directions
+        are separate, so sending does not disturb that ``recv``). The
+        job's generator is still closed after that step; a cancel that
+        finds no job running is dropped by the child."""
+        self._send(("cancel",))
 
     def close(self) -> None:
         """Forward :meth:`Miner.close` (a trace flush, a pod's follower
         release); the child keeps serving. :meth:`shutdown` ends it."""
         if self._proc.is_alive():
-            self._conn.send(("close",))
+            self._send(("close",))
 
     def join(self) -> None:
         self._proc.join()
@@ -127,7 +147,7 @@ class ProcessMiner(Miner):
     def shutdown(self, grace: float = 10.0) -> None:
         if self._proc.is_alive():
             try:
-                self._conn.send(("stop",))
+                self._send(("stop",))
             except OSError:
                 pass
             self._proc.join(grace)

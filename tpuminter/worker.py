@@ -98,6 +98,13 @@ class Miner:
         :attr:`backend`."""
         return workloads.compute(request, engine=self.backend)
 
+    def cancel(self) -> None:
+        """Stop the running chunk at its next yield point. Called from
+        the role loop's thread while a step of the chunk may be running
+        on another; the generator is still closed once that step
+        returns. A no-op here: an in-process miner's step cannot be
+        interrupted, so closing the generator is the earliest stop."""
+
 
 class CpuMiner(Miner):
     """hashlib-backed reference miner (≙ the reference's Go hot loop).
@@ -338,9 +345,14 @@ async def run_miner(
     decision on the worker reads the clock.
 
     ≙ reference ``miner.go`` ``main`` (SURVEY.md §3.2), with Cancel
-    handling layered in: while a chunk is being mined, an LSP read is kept
-    in flight so a ``Cancel`` for the active job abandons it immediately;
-    any other message read mid-mine is queued and handled after.
+    handling layered in: while a generator step runs, the loop waits on
+    the step and an LSP read together, so every message is handled as it
+    arrives. A ``Cancel`` for the chunk being mined calls
+    :meth:`Miner.cancel` at once (a :class:`~tpuminter.miner_proc.
+    ProcessMiner` forwards it to its child, which stops at its next
+    yield point), and the generator is closed when the running step
+    returns; any other message read mid-mine is queued in arrival order
+    and handled after.
 
     ``binary`` advertises the struct-packed codec in the Join
     (``protocol`` module docstring): Results/Refuses switch to binary
@@ -493,9 +505,60 @@ async def run_miner(
             cancelled = False
             _done = object()
             while True:
-                item = await loop.run_in_executor(None, next, gen, _done)
-                if item is _done:
-                    break  # generator ended without a Result
+                # one step at a time: a ProcessMiner's steps receive on
+                # its pipe, and two receiving threads would race
+                step = loop.run_in_executor(None, next, gen, _done)
+                while not step.done():
+                    if read_task is None:
+                        read_task = asyncio.ensure_future(client.read())
+                    await asyncio.wait(
+                        (step, read_task), return_when=asyncio.FIRST_COMPLETED
+                    )
+                    if not read_task.done():
+                        continue
+                    try:
+                        raw = read_task.result()
+                    except LspConnectionLost:
+                        # stop the chunk and let its step return before
+                        # leaving: a reconnect's first step must not
+                        # receive on the miner beside this one
+                        miner.cancel()
+                        await asyncio.gather(step, return_exceptions=True)
+                        await loop.run_in_executor(None, gen.close)
+                        raise
+                    read_task = None
+                    note_codec(raw)
+                    inner = _safe_decode(raw)
+                    if (
+                        not cancelled and isinstance(inner, Cancel)
+                        and inner.job_id == msg.job_id
+                    ):
+                        cancelled = True
+                        # this branch consumes the Cancel, so the
+                        # top-level Cancel handler never sees it: evict
+                        # the template HERE too. Any Assign of the dead
+                        # job still queued behind this chunk (pipelined
+                        # dispatch) then takes the Refuse seam instead
+                        # of burning a whole chunk of device time on
+                        # retired work. Do NOT purge the pending queue
+                        # itself: a hedge-released job is still LIVE,
+                        # and its post-Cancel re-dispatch (Setup +
+                        # Assign, queued behind this Cancel) must
+                        # survive — the in-order re-shipped Setup
+                        # restores the template before that Assign is
+                        # handled, while silently dropping it would
+                        # wedge this worker busy-forever on the
+                        # coordinator's books.
+                        templates.pop(inner.job_id, None)
+                        miner.cancel()
+                    elif inner is not None:
+                        pending.put_nowait(inner)
+                item = step.result()
+                if cancelled or item is _done:
+                    # the generator is not resumed again: close it now
+                    # (off the loop thread: its cleanup is miner code)
+                    await loop.run_in_executor(None, gen.close)
+                    break
                 if item is not None:
                     result = item
                     break
@@ -515,35 +578,7 @@ async def run_miner(
                         ))
                         last_beacon = mono()
                         beacon_hw = hw
-                if read_task is None:
-                    read_task = asyncio.ensure_future(client.read())
-                if read_task.done():
-                    raw = read_task.result()  # raises here if conn lost
-                    read_task = None
-                    note_codec(raw)
-                    inner = _safe_decode(raw)
-                    if isinstance(inner, Cancel) and inner.job_id == msg.job_id:
-                        cancelled = True
-                        # this branch consumes the Cancel, so the
-                        # top-level Cancel handler never sees it: evict
-                        # the template HERE too. Any Assign of the dead
-                        # job still queued behind this chunk (pipelined
-                        # dispatch) then takes the Refuse seam instead
-                        # of burning a whole chunk of device time on
-                        # retired work. Do NOT purge the pending queue
-                        # itself: a hedge-released job is still LIVE,
-                        # and its post-Cancel re-dispatch (Setup +
-                        # Assign, already queued by the time we process
-                        # this Cancel) must survive — the in-order
-                        # re-shipped Setup restores the template before
-                        # that Assign is handled, while silently
-                        # dropping it would wedge this worker
-                        # busy-forever on the coordinator's books.
-                        templates.pop(inner.job_id, None)
-                        break
-                    if inner is not None:
-                        pending.put_nowait(inner)
-            if cancelled or result is None:
+            if result is None:
                 log.info("worker: job %d cancelled mid-chunk", msg.job_id)
                 continue
             if on_result is not None:
