@@ -104,10 +104,13 @@ class Cluster:
         self._spawn("worker", [f"127.0.0.1:{self.port}", *worker_args])
 
     def _spawn(self, role, args):
+        # a session of its own: stopping the role's process group also
+        # reaches the device miner that the worker spawns
         log = open(os.path.join(OUT, f"{role}.log"), "w")
         self.procs[role] = (subprocess.Popen(
             [sys.executable, "-m", f"tpuminter.{role}", *args],
             cwd=ROOT, env=self.env, stdout=log, stderr=subprocess.STDOUT,
+            start_new_session=True,
         ), log)
 
     def log(self, role) -> str:
@@ -158,21 +161,44 @@ class Cluster:
         return out
 
     def stop(self):
-        """Stop every child; the worker and coordinator must still have
-        been running (a child that died earlier is a failure)."""
+        """Stop every child, the worker first with SIGINT (it shuts its
+        device miner down cleanly), and wait until no process of either
+        group is left: the chip is free only once the miner child has
+        exited. The worker and coordinator must still have been running
+        (a child that died earlier is a failure)."""
         died = [r for r, (p, _) in self.procs.items() if p.poll() is not None]
-        for proc, log in self.procs.values():
+        for role in ("worker", "coordinator"):
+            if role not in self.procs:
+                continue
+            proc, log = self.procs[role]
             if proc.poll() is None:
-                proc.terminate()
-        for proc, log in self.procs.values():
-            try:
-                proc.wait(timeout=20)
-            except subprocess.TimeoutExpired:
-                proc.kill()
-                proc.wait()
+                proc.send_signal(signal.SIGINT)
+                try:
+                    proc.wait(timeout=30)
+                except subprocess.TimeoutExpired:
+                    proc.kill()
+                    proc.wait()
+            _reap_group(proc.pid)
             log.close()
         if died:
             raise SmokeError(f"{', '.join(died)} exited before the end")
+
+
+def _reap_group(pgid: int, timeout: float = 30.0) -> None:
+    """Signal whatever is left of process group ``pgid`` (SIGTERM, then
+    SIGKILL after ``timeout``) until the group is empty."""
+    start = time.time()
+    sig = signal.SIGTERM
+    while True:
+        try:
+            os.killpg(pgid, sig)
+        except (ProcessLookupError, PermissionError):
+            return
+        if time.time() - start > 2 * timeout:
+            raise SmokeError(f"process group {pgid} did not end")
+        if time.time() - start > timeout:
+            sig = signal.SIGKILL
+        time.sleep(0.2)
 
 
 def _result_line(out: str) -> str:

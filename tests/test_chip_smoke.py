@@ -74,3 +74,43 @@ def test_refuses_to_run_outside_a_checkout(tmp_path):
     )
     assert proc.returncode != 0
     assert '"ok"' not in proc.stdout
+
+
+def _children(pid: int) -> list:
+    out = []
+    for entry in os.listdir("/proc"):
+        try:
+            with open(f"/proc/{entry}/stat") as fh:
+                stat = fh.read()
+        except (OSError, ValueError):
+            continue
+        if int(stat.rsplit(")", 1)[1].split()[1]) == pid:
+            out.append(int(entry))
+    return out
+
+
+def _running(pid: int) -> bool:
+    """Alive and not a zombie (a zombie holds no device)."""
+    try:
+        with open(f"/proc/{pid}/stat") as fh:
+            return fh.read().rsplit(")", 1)[1].split()[0] != "Z"
+    except FileNotFoundError:
+        return False
+
+
+@pytest.mark.skipif(not os.path.isdir("/proc"), reason="reads /proc")
+def test_stop_leaves_no_process_of_the_worker(tmp_path, monkeypatch):
+    """The smoke's last check takes the chip in its own process, so the
+    worker's device-miner child must have exited by then: ``stop``
+    returns only once it has."""
+    monkeypatch.setattr(cs, "OUT", str(tmp_path))
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    cl = cs.Cluster(["--backend", "jax"])
+    try:
+        cl.wait_for("worker", r"device: platform=cpu", 120)
+        worker = cl.procs["worker"][0].pid
+        children = _children(worker)
+    finally:
+        cl.stop()
+    assert children
+    assert not any(_running(pid) for pid in [worker] + children)

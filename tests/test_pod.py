@@ -314,3 +314,75 @@ def test_pod_miner_rolled_batched_finds_exact_first_winner(mesh):
     r2 = _drain(miner.mine(mk(1, 23)))
     assert not r2.found and (r2.hash_value, r2.nonce) == (h_c, g_c)
     assert r2.searched == ens << nb
+
+
+# -- one four-chip host (a v5e 2x2) as one pod worker -------------------------
+
+
+@pytest.fixture(scope="module")
+def mesh4():
+    return make_mesh(jax.devices()[:4])
+
+
+@pytest.fixture(scope="module")
+def sweep4(mesh4):
+    template = ops.header_template(GEN.pack())
+    return build_candidate_sweep(
+        mesh4, template, slab_per_device=256, n_slabs=4, kernel="jnp"
+    )
+
+
+def test_pod_candidate_sweep_has_its_own_program_name(sweep4):
+    """A device trace tells the pod sweep from every other pod program
+    (each a shard_map of a function named ``per_device``) by this name."""
+    text = sweep4.lower(jnp.uint32(0), _biased_cap(TARGET)).as_text()
+    assert "module @jit_pod_candidate_sweep " in text
+
+
+@pytest.mark.parametrize("stripe", [0, 2])
+def test_pod_sweep_stops_after_the_stripe_of_its_first_candidate(sweep4, stripe):
+    # stripes of 4 chips x 256 nonces: the genesis nonce lies 300 nonces
+    # into `stripe`, in chip 1's slab, and no candidate lies below it
+    offset = stripe * 4 * 256 + 256 + 44
+    found, first, stripes = sweep4(
+        jnp.uint32(GEN.nonce - offset), _biased_cap(TARGET)
+    )
+    assert (int(found), int(first), int(stripes)) == (1, offset, stripe + 1)
+
+
+def _first_share(lo: int, hi: int):
+    """The first nonce in [lo, hi] whose genesis header meets the
+    diff-1 target, and its hash, by hashlib alone."""
+    import hashlib
+    import struct
+
+    prefix = GEN.pack()[:76]
+    for n in range(lo, hi + 1):
+        digest = hashlib.sha256(
+            hashlib.sha256(prefix + struct.pack("<I", n)).digest()
+        ).digest()
+        h = int.from_bytes(digest, "little")
+        if h <= TARGET:
+            return n, h
+    return None
+
+
+@pytest.fixture(scope="module")
+def pod4(mesh4):
+    return PodMiner(mesh=mesh4, slab_per_device=256, n_slabs=4, kernel="jnp")
+
+
+@pytest.mark.parametrize("below", [3000, 1500, 0])
+def test_pod_miner_on_four_chips_answers_genesis_jobs(pod4, below):
+    """Jobs shaped as the genesis benchmark's: [lo, 2^32 - 1] with the
+    genesis nonce at most 3000 above lo."""
+    lo = GEN.nonce - below
+    want = _first_share(lo, GEN.nonce)
+    assert want == (GEN.nonce, GEN.block_hash_int())
+    req = Request(
+        job_id=30 + below, mode=PowMode.TARGET, lower=lo, upper=(1 << 32) - 1,
+        header=GEN.pack(), target=TARGET,
+    )
+    result = _drain(pod4.mine(req))
+    assert result.found and (result.nonce, result.hash_value) == want
+    assert result.searched >= below + 1

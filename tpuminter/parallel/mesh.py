@@ -414,7 +414,13 @@ def build_candidate_sweep(
     sharded = _shard_map(
         per_device, mesh, in_specs=(P(),) * n_in, out_specs=(P(), P(), P())
     )
-    return jax.jit(sharded)
+
+    def pod_candidate_sweep(*args):
+        # the program's own name in a device trace (``XLA Modules`` shows
+        # ``jit_pod_candidate_sweep``), apart from the other pod programs
+        return sharded(*args)
+
+    return jax.jit(pod_candidate_sweep)
 
 
 def build_rolled_sweep(

@@ -309,7 +309,9 @@ class PodMiner(Miner):
         design guarantees pod-wide (``parallel.build_candidate_sweep``)."""
 
         def sweep(base: int, n: int):
-            found, off, _ = sweep_fn(jnp.uint32(base))  # stripes unused
+            # the stripes a call took are not pulled to the host: a
+            # device trace shows them, one kernel op a stripe per chip
+            found, off, _ = sweep_fn(jnp.uint32(base))
             return pack_handle(found, off)
 
         return CandidateSearch(
