@@ -1667,3 +1667,186 @@ def test_hedge_loser_with_pipelined_chunks_releases_them_all():
             await cluster.close()
 
     run(scenario())
+
+
+# ---------------------------------------------------------------------------
+# the answered-job hold: a worker whose own found TARGET/SCRYPT Result
+# passes the coordinator's check mines no further chunk of that job
+# ---------------------------------------------------------------------------
+
+class RecordingCpuMiner(CpuMiner):
+    """A CPU miner that records the ``(job_id, lower, upper)`` of every
+    chunk it starts to mine."""
+
+    def __init__(self, **kwargs):
+        super().__init__(**kwargs)
+        self.mined = []
+
+    def mine(self, request):
+        self.mined.append((request.job_id, request.lower, request.upper))
+        yield from super().mine(request)
+
+
+class OnceWrongMiner(RecordingCpuMiner):
+    """Claims a winner that is no winner for its first chunk, then
+    mines honestly."""
+
+    def mine(self, request):
+        if self.mined:
+            yield from super().mine(request)
+            return
+        self.mined.append((request.job_id, request.lower, request.upper))
+        yield Result(
+            request.job_id, request.mode, request.lower, hash_value=0,
+            found=True, searched=1, chunk_id=request.chunk_id,
+        )
+
+
+def _genesis_job(job_id: int, chunk: int) -> Request:
+    """A TARGET job over two chunks of ``chunk`` nonces; its one winner,
+    the genesis nonce, lies in the first."""
+    g = chain.GENESIS_HEADER.nonce
+    return Request(
+        job_id=job_id, mode=PowMode.TARGET, lower=g - chunk // 2,
+        upper=g + chunk + chunk // 2 - 1, header=chain.GENESIS_HEADER.pack(),
+        target=chain.bits_to_target(0x1D00FFFF),
+    )
+
+
+def test_wrong_winner_holds_nothing():
+    """A miner's claimed winner that fails the coordinator's check
+    holds nothing on the worker: the coordinator rejects and requeues
+    the chunk, the pipelined chunk is mined, and the job still gets its
+    true answer."""
+    chunk = 1024
+    req = _genesis_job(1, chunk)
+    miner = OnceWrongMiner()
+
+    async def scenario():
+        cluster = await Cluster.create(n_miners=0, chunk_size=chunk)
+        await cluster.add_miner(miner)
+        try:
+            result = await asyncio.wait_for(
+                submit("127.0.0.1", cluster.coord.port, req, params=FAST),
+                30.0,
+            )
+            assert cluster.coord.stats["results_rejected"] == 1
+            return result
+        finally:
+            await cluster.close()
+
+    result = run(scenario())
+    assert (result.found, result.nonce) == (True, chain.GENESIS_HEADER.nonce)
+    first = (1, req.lower, req.lower + chunk - 1)
+    second = (1, req.lower + chunk, req.upper)
+    assert miner.mined == [first, second, first]
+
+
+@pytest.mark.parametrize("trigger", ["own_cancel", "next_job", "other_cancel"])
+def test_held_chunk_dropped_by_cancel_released_by_other_message(trigger):
+    """A fake coordinator pipelines two chunks of a TARGET job whose
+    winner is in the first. The worker answers the first and holds the
+    second. ``own_cancel``: the job's Cancel drops it unmined, with no
+    Result. Any other message (``next_job``: the next job's Setup and
+    Assign; ``other_cancel``: another job's Cancel) releases it first:
+    it is mined, and answered, before that message is handled."""
+    from tpuminter.lsp import LspServer
+    from tpuminter.protocol import (
+        Assign, Cancel, Join, Setup, decode_msg, encode_msg,
+    )
+
+    g = chain.GENESIS_HEADER.nonce
+    job1 = Request(
+        job_id=1, mode=PowMode.TARGET, lower=g - 10, upper=g + 40,
+        header=chain.GENESIS_HEADER.pack(),
+        target=chain.bits_to_target(0x1D00FFFF),
+    )
+    job2 = Request(job_id=2, mode=PowMode.MIN, lower=0, upper=99,
+                   data=b"after the hold")
+    miner = RecordingCpuMiner()
+
+    async def scenario():
+        server = await LspServer.create(params=FAST)
+        worker = asyncio.ensure_future(
+            run_miner("127.0.0.1", server.port, miner, params=FAST)
+        )
+        try:
+            conn, raw = await asyncio.wait_for(server.read(), 10.0)
+            assert isinstance(decode_msg(raw), Join)
+
+            async def next_result(timeout=10.0):
+                _, raw = await asyncio.wait_for(server.read(), timeout)
+                return decode_msg(raw)
+
+            for m in (Setup(job1), Assign(1, 11, g - 10, g + 10),
+                      Assign(1, 12, g + 11, g + 40)):
+                server.write(conn, encode_msg(m))
+            won = await next_result()
+            assert (won.chunk_id, won.found, won.nonce) == (11, True, g)
+            with pytest.raises(asyncio.TimeoutError):
+                await next_result(timeout=0.5)  # chunk 12 is held
+            assert miner.mined == [(1, g - 10, g + 10)]
+            if trigger == "own_cancel":
+                server.write(conn, encode_msg(Cancel(1)))
+            elif trigger == "other_cancel":
+                server.write(conn, encode_msg(Cancel(9)))
+            for m in (Setup(job2), Assign(2, 13, 0, 99)):
+                server.write(conn, encode_msg(m))
+            got = [await next_result()]
+            if trigger != "own_cancel":
+                got.append(await next_result())
+            return got
+        finally:
+            worker.cancel()
+            await asyncio.gather(worker, return_exceptions=True)
+            await server.close()
+
+    got = run(scenario())
+    want2 = brute_min(job2.data, 0, 99)
+    assert (got[-1].chunk_id, got[-1].hash_value, got[-1].nonce) == (
+        13, *want2
+    )
+    if trigger == "own_cancel":
+        assert miner.mined == [(1, g - 10, g + 10), (2, 0, 99)]
+    else:
+        assert (got[0].chunk_id, got[0].found) == (12, False)
+        assert miner.mined == [
+            (1, g - 10, g + 10), (1, g + 11, g + 40), (2, 0, 99)
+        ]
+
+
+@pytest.mark.parametrize("mode", [PowMode.MIN, PowMode.TARGET, PowMode.SCRYPT])
+def test_jobs_without_a_found_winner_mine_every_chunk(mode):
+    """A MIN job, whose Results are always ``found``, and targeted jobs
+    that no nonce wins hold nothing: every chunk is mined, once."""
+    chunk, upper = 32, 1023  # SCRYPT carves 512 at the least
+    if mode == PowMode.MIN:
+        req = Request(job_id=1, mode=mode, lower=0, upper=upper,
+                      data=b"nothing held")
+    else:
+        req = Request(job_id=1, mode=mode, lower=0, upper=upper,
+                      header=chain.GENESIS_HEADER.pack(), target=1)
+    miner = RecordingCpuMiner(batch=8)
+
+    async def scenario():
+        cluster = await Cluster.create(n_miners=0, chunk_size=chunk)
+        await cluster.add_miner(miner)
+        try:
+            return await asyncio.wait_for(
+                submit("127.0.0.1", cluster.coord.port, req, params=FAST),
+                30.0,
+            )
+        finally:
+            await cluster.close()
+
+    result = run(scenario())
+    mined = sorted(miner.mined)
+    assert len(mined) >= 2  # a pipelined chunk behind each Result
+    assert [lo for _, lo, _ in mined] == [0] + [hi + 1 for _, _, hi in mined[:-1]]
+    assert mined[-1][2] == upper
+    if mode == PowMode.MIN:
+        assert (result.hash_value, result.nonce) == brute_min(
+            req.data, 0, upper
+        )
+    else:
+        assert not result.found

@@ -219,9 +219,9 @@ def test_close_keeps_the_child_serving():
 
 
 class SlowMiner(CpuMiner):
-    """A CPU miner that sleeps ``pause`` seconds in each step and logs
-    ``job chunk time`` (the system-wide monotonic clock) to ``log`` as
-    each step ends."""
+    """A CPU miner that sleeps ``pause`` seconds in each step but the
+    last and logs ``job chunk time`` (the system-wide monotonic clock)
+    to ``log`` as each step ends."""
 
     def __init__(self, log: str, pause: float):
         super().__init__(batch=64)
@@ -231,9 +231,9 @@ class SlowMiner(CpuMiner):
         for item in super().mine(request):
             if item is None:
                 time.sleep(self.pause)
-                with open(self.log, "a") as f:
-                    f.write(f"{request.job_id} {request.chunk_id} "
-                            f"{time.monotonic()}\n")
+            with open(self.log, "a") as f:
+                f.write(f"{request.job_id} {request.chunk_id} "
+                        f"{time.monotonic()}\n")
             yield item
 
 
@@ -299,10 +299,9 @@ class RecordingProcessMiner(ProcessMiner):
         super().cancel()
 
 
-def _early_winner(job_id: int, chunk: int, batch: int = 64):
+def _early_winner(job_id: int, chunk: int, batch: int = 64, at: int = 0):
     """A TARGET job over two chunks whose one winner, the job's least
-    hash, lies in the first batch: the first chunk answers the job at
-    once and the coordinator cancels the second, pipelined behind it."""
+    hash, lies in the first batch of chunk ``at`` (0 or 1)."""
     base = chain.GENESIS_HEADER
     for dt in range(1, 1000):
         header = dataclasses.replace(base, timestamp=base.timestamp + dt).pack()
@@ -311,21 +310,82 @@ def _early_winner(job_id: int, chunk: int, batch: int = 64):
             for n in range(2 * chunk)
         ]
         w = min(range(2 * chunk), key=hashes.__getitem__)
-        if w < batch:
+        if at * chunk <= w < at * chunk + batch:
             req = Request(job_id, PowMode.TARGET, 0, 2 * chunk - 1,
                           header=header, target=hashes[w])
             return req, w
     raise AssertionError("no header with an early winner")
 
 
+def _step_chunks(log: str, job_id: int) -> set:
+    with open(log) as f:
+        rows = [line.split() for line in f]
+    return {int(chunk) for job, chunk, _ in rows if int(job) == job_id}
+
+
 @pytest.mark.parametrize("next_job", [False, True])
 def test_cancel_reaches_the_child_mid_step(tmp_path, next_job):
     """The role loop forwards a Cancel for the chunk being mined while
     its step runs: the child runs at most one more step of it, also
-    when no further job ever arrives. ``next_job``: the next job's Setup
-    and Assign, queued right behind the Cancel, are still handled."""
+    when no further job ever arrives. The job is answered by another
+    worker: this one mines the first chunk, a fast helper that joins
+    later takes the second, which holds the winner (one chunk a worker
+    at a time). ``next_job``: the next job's Setup and Assign, queued
+    right behind the Cancel, are still handled."""
     log = str(tmp_path / "steps")
     chunk = 1024  # 16 steps a chunk
+    req1, winner = _early_winner(1, chunk, at=1)
+    req2 = _toy(2, 255)
+    want2 = min((chain.toy_hash(req2.data, n), n) for n in range(256))
+    miner = RecordingProcessMiner(SlowMiner, log, pause=0.2)
+
+    async def scenario(port):
+        worker = asyncio.ensure_future(
+            run_miner("127.0.0.1", port, miner, params=FAST)
+        )
+        await asyncio.sleep(0.3)
+        job1 = asyncio.ensure_future(
+            submit("127.0.0.1", port, req1, params=FAST)
+        )
+        while not os.path.exists(log):  # this worker mines chunk 1
+            await asyncio.sleep(0.05)
+        helper = asyncio.ensure_future(
+            run_miner("127.0.0.1", port, CpuMiner(), params=FAST)
+        )
+        r1 = await asyncio.wait_for(job1, 30)
+        r2 = None
+        if next_job:
+            r2 = await asyncio.wait_for(
+                submit("127.0.0.1", port, req2, params=FAST), 30
+            )
+        await asyncio.sleep(1.0)  # the child would mine on meanwhile
+        assert not worker.done()
+        for t in (worker, helper):
+            t.cancel()
+        await asyncio.gather(worker, helper, return_exceptions=True)
+        return r1, r2
+
+    try:
+        with _coordinator_cli(
+            "--chunk-size", str(chunk), "--pipeline-depth", "1"
+        ) as port:
+            r1, r2 = asyncio.run(scenario(port))
+    finally:
+        miner.shutdown()
+    assert (r1.found, r1.nonce) == (True, winner)
+    assert len(miner.cancels) == 1
+    assert len([t for t in _step_times(log, 1) if t > miner.cancels[0]]) <= 1
+    if next_job:
+        assert (r2.hash_value, r2.nonce) == want2
+
+
+def test_answered_job_holds_its_queued_chunk(tmp_path):
+    """The worker's first chunk answers the job; the second, pipelined
+    behind it, is held until the job's Cancel drops it, so it never
+    reaches the child and no cancel is sent there. The answer is the
+    same, and the next job on the same worker is exact."""
+    log = str(tmp_path / "steps")
+    chunk = 1024
     req1, winner = _early_winner(1, chunk)
     req2 = _toy(2, 255)
     want2 = min((chain.toy_hash(req2.data, n), n) for n in range(256))
@@ -339,12 +399,10 @@ def test_cancel_reaches_the_child_mid_step(tmp_path, next_job):
         r1 = await asyncio.wait_for(
             submit("127.0.0.1", port, req1, params=FAST), 30
         )
-        r2 = None
-        if next_job:
-            r2 = await asyncio.wait_for(
-                submit("127.0.0.1", port, req2, params=FAST), 30
-            )
-        await asyncio.sleep(1.0)  # the child would mine on meanwhile
+        r2 = await asyncio.wait_for(
+            submit("127.0.0.1", port, req2, params=FAST), 30
+        )
+        await asyncio.sleep(1.0)  # a released chunk would be mined now
         assert not worker.done()
         worker.cancel()
         await asyncio.gather(worker, return_exceptions=True)
@@ -356,10 +414,9 @@ def test_cancel_reaches_the_child_mid_step(tmp_path, next_job):
     finally:
         miner.shutdown()
     assert (r1.found, r1.nonce) == (True, winner)
-    assert len(miner.cancels) == 1
-    assert len([t for t in _step_times(log, 1) if t > miner.cancels[0]]) <= 1
-    if next_job:
-        assert (r2.hash_value, r2.nonce) == want2
+    assert (r2.hash_value, r2.nonce) == want2
+    assert len(_step_chunks(log, 1)) == 1  # the winner's chunk alone
+    assert miner.cancels == []
 
 
 def test_session_lost_mid_step(tmp_path):

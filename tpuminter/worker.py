@@ -27,10 +27,12 @@ import logging
 import random
 import struct
 import time
+from collections import deque
 from typing import Callable, Iterator, Optional
 
 from tpuminter import chain
 from tpuminter import workloads
+from tpuminter.coordinator import Coordinator
 from tpuminter.lsp import LspClient, LspConnectError, LspConnectionLost, Params
 from tpuminter.lsp.params import jittered_backoff
 from tpuminter.lsp.params import FAST
@@ -354,6 +356,22 @@ async def run_miner(
     returns; any other message read mid-mine is queued in arrival order
     and handled after.
 
+    A job this worker has just answered is not mined further. The
+    coordinator finishes a targeted job on any accepted found Result
+    (``Coordinator._accept_result``, and ``_settle_audit`` for an audit
+    that mines a winner) and retires it at once, so the next message it
+    sends this worker is ``Cancel(job)``, which covers every chunk of
+    the job the worker still holds. So once the loop writes a found
+    TARGET or SCRYPT Result that passes the coordinator's own check
+    (``Coordinator._verify_result``), it HOLDS every Assign or
+    RollAssign of that job it reads next (the pipelined chunk queued
+    behind the winner's) instead of mining it. The job's Cancel drops
+    the held chunks, with no Result and no Refuse: the coordinator has
+    already booked them cancelled. Any other message first releases
+    them, to be mined in arrival order as if never held, so a Cancel
+    that never comes costs time, not coverage; a Result that fails the
+    check holds nothing.
+
     ``binary`` advertises the struct-packed codec in the Join
     (``protocol`` module docstring): Results/Refuses switch to binary
     only after the coordinator has SENT us a binary payload — proof it
@@ -395,7 +413,12 @@ async def run_miner(
         if binary and not speak_binary and payload_is_binary(raw):
             speak_binary = True
 
-    pending: "asyncio.Queue[Message]" = asyncio.Queue()
+    pending: "deque[Message]" = deque()
+    #: the job this worker's own found, checked Result answered, and
+    #: the Assigns/RollAssigns of it read since (docstring: the hold)
+    answered: Optional[int] = None
+    held: list = []
+    dropped = 0
     read_task: Optional[asyncio.Task] = None
     #: job_id → template Request from a Setup (insertion-ordered so the
     #: cap evicts oldest-first; Cancel evicts eagerly, the cap only mops
@@ -406,8 +429,8 @@ async def run_miner(
     try:
         while True:
             # -- next message: drained backlog first, then the wire ------
-            if not pending.empty():
-                msg = pending.get_nowait()
+            if pending:
+                msg = pending.popleft()
             else:
                 if read_task is None:
                     read_task = asyncio.ensure_future(client.read())
@@ -416,6 +439,30 @@ async def run_miner(
                 note_codec(raw)
                 msg = _safe_decode(raw)
                 if msg is None:
+                    continue
+            if answered is not None:
+                if (
+                    isinstance(msg, (Assign, RollAssign))
+                    and msg.job_id == answered
+                ):
+                    held.append(msg)
+                    continue
+                job_id, released = answered, held
+                answered, held = None, []
+                if isinstance(msg, Cancel) and msg.job_id == job_id:
+                    # the coordinator booked these chunks cancelled when
+                    # it wrote this Cancel: no Result, no Refuse
+                    for h in released:
+                        log.info(
+                            "worker: job %d answered; chunk %d dropped "
+                            "unmined", h.job_id, h.chunk_id,
+                        )
+                    dropped += len(released)
+                elif released:
+                    # backstop: mine the held chunks in arrival order,
+                    # as if never held, then handle this message
+                    pending.appendleft(msg)
+                    pending.extendleft(reversed(released))
                     continue
             if isinstance(msg, Cancel):
                 templates.pop(msg.job_id, None)
@@ -552,7 +599,7 @@ async def run_miner(
                         templates.pop(inner.job_id, None)
                         miner.cancel()
                     elif inner is not None:
-                        pending.put_nowait(inner)
+                        pending.append(inner)
                 item = step.result()
                 if cancelled or item is _done:
                     # the generator is not resumed again: close it now
@@ -584,9 +631,19 @@ async def run_miner(
             if on_result is not None:
                 on_result(result)
             client.write(encode_msg(result, binary=speak_binary))
+            if (
+                isinstance(result, Result) and result.found
+                and result.mode.targeted
+                and Coordinator._verify_result(msg, result)
+            ):
+                answered = msg.job_id
     except LspConnectionLost:
         log.info("worker: coordinator lost, exiting")
     finally:
+        log.info(
+            "worker: session ended; %d chunk(s) of answered jobs dropped "
+            "unmined", dropped,
+        )
         if read_task is not None:
             read_task.cancel()
         closer = getattr(miner, "close", None)
