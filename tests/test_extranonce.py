@@ -86,16 +86,32 @@ def test_rolled_header_matches_manual_merkle():
 # the device roll (jnp path; Pallas twin tested on the real chip)
 # ---------------------------------------------------------------------------
 
+def _roll_one(hdr80, prefix, suffix, en_size, branch):
+    """``roll(en) -> (midstate, tail_words)``: row 0 of the batched
+    device roll of the single extranonce ``en``."""
+    batch = merkle.make_extranonce_roll_batch(
+        hdr80, prefix, suffix, en_size, branch)
+
+    def roll(en):
+        mids, tails = batch(
+            jnp.asarray([en >> 32], jnp.uint32),
+            jnp.asarray([en & 0xFFFFFFFF], jnp.uint32),
+        )
+        return mids[0], tails[0]
+
+    return roll
+
+
 def test_device_roll_matches_host_template():
     """roll(en) ≡ header_template(rolled_header(en)) for midstate AND
     tail words — the exact values the search kernels specialize on."""
     prefix, suffix, branch, hdr80 = fixture()
     cb = chain.CoinbaseTemplate(prefix, suffix, 4)
-    roll = merkle.make_extranonce_roll(hdr80, prefix, suffix, 4, branch)
+    roll = _roll_one(hdr80, prefix, suffix, 4, branch)
     for en in (0, 1, 2, 0xDEADBEEF):
         want_hdr = chain.rolled_header(hdr80, cb, branch, en)
         t = ops.header_template(want_hdr.pack())
-        mid, tw = roll(jnp.uint32(0), jnp.uint32(en))
+        mid, tw = roll(en)
         assert tuple(int(x) for x in np.asarray(mid)) == t.midstate
         assert tuple(int(x) for x in np.asarray(tw)) == want_hdr.tail_words()
 
@@ -104,10 +120,10 @@ def test_device_roll_wide_extranonce():
     """8-byte extranonces travel as (hi, lo) u32 pairs."""
     prefix, suffix, branch, hdr80 = fixture()
     cb = chain.CoinbaseTemplate(prefix, suffix, 8)
-    roll = merkle.make_extranonce_roll(hdr80, prefix, suffix, 8, branch)
+    roll = _roll_one(hdr80, prefix, suffix, 8, branch)
     en = 0x0123456789ABCDEF
     want = ops.header_template(chain.rolled_header(hdr80, cb, branch, en).pack())
-    mid, _ = roll(jnp.uint32(en >> 32), jnp.uint32(en & 0xFFFFFFFF))
+    mid, _ = roll(en)
     assert tuple(int(x) for x in np.asarray(mid)) == want.midstate
 
 
@@ -115,9 +131,9 @@ def test_device_roll_empty_branch():
     """A block whose only tx is the coinbase: root == txid."""
     prefix, suffix, _, hdr80 = fixture()
     cb = chain.CoinbaseTemplate(prefix, suffix, 4)
-    roll = merkle.make_extranonce_roll(hdr80, prefix, suffix, 4, ())
+    roll = _roll_one(hdr80, prefix, suffix, 4, ())
     want = ops.header_template(chain.rolled_header(hdr80, cb, (), 9).pack())
-    mid, tw = roll(jnp.uint32(0), jnp.uint32(9))
+    mid, tw = roll(9)
     assert tuple(int(x) for x in np.asarray(mid)) == want.midstate
 
 
@@ -125,9 +141,9 @@ def test_header_digest_dyn_matches_hashlib():
     """The dynamic-header hash fed by the roll ≡ hashlib double-SHA."""
     prefix, suffix, branch, hdr80 = fixture()
     cb = chain.CoinbaseTemplate(prefix, suffix, 4)
-    roll = merkle.make_extranonce_roll(hdr80, prefix, suffix, 4, branch)
+    roll = _roll_one(hdr80, prefix, suffix, 4, branch)
     for en in (0, 3):
-        mid, tw = roll(jnp.uint32(0), jnp.uint32(en))
+        mid, tw = roll(en)
         nonces = jnp.asarray(np.array([0, 1, 77, 2**32 - 1], np.uint32))
         dw = np.asarray(ops.header_digest_dyn(mid, tw, nonces))
         p76 = chain.rolled_header(hdr80, cb, branch, en).pack()[:76]
@@ -377,9 +393,10 @@ def test_rolled_job_end_to_end(ground_truth):
 # ---------------------------------------------------------------------------
 
 def test_batched_roll_property_pin():
-    """Seeded property pin: batched roll rows == per-extranonce scalar
-    ``roll()`` == midstates derived from ``chain.rolled_header`` +
-    hashlib, across random (extranonce_size, branch depth, B) combos."""
+    """Seeded property pin: batched roll rows == the same extranonce
+    rolled alone (a batch of one) == midstates derived from
+    ``chain.rolled_header`` + hashlib, across random (extranonce_size,
+    branch depth, B) combos."""
     import random as _random
 
     hdr80 = chain.GENESIS_HEADER.pack()
@@ -397,9 +414,7 @@ def test_batched_roll_property_pin():
         batch = merkle.make_extranonce_roll_batch(
             hdr80, prefix, suffix, en_size, branch
         )
-        scalar = merkle.make_extranonce_roll(
-            hdr80, prefix, suffix, en_size, branch
-        )
+        alone = _roll_one(hdr80, prefix, suffix, en_size, branch)
         mids, tails = batch(
             jnp.asarray(np.array([e >> 32 for e in ens], np.uint32)),
             jnp.asarray(np.array([e & 0xFFFFFFFF for e in ens], np.uint32)),
@@ -408,9 +423,7 @@ def test_batched_roll_property_pin():
         for i, en in enumerate(ens):
             want_hdr = chain.rolled_header(hdr80, cb, branch, en)
             t = ops.header_template(want_hdr.pack())  # hashlib-derived
-            s_mid, s_tw = scalar(
-                jnp.uint32(en >> 32), jnp.uint32(en & 0xFFFFFFFF)
-            )
+            s_mid, s_tw = alone(en)
             assert tuple(int(x) for x in mids[i]) == t.midstate, (seed, en)
             assert tuple(int(x) for x in tails[i]) == want_hdr.tail_words()
             assert (np.asarray(s_mid) == mids[i]).all()
@@ -458,26 +471,32 @@ def _drain(gen):
     return result
 
 
-def test_jax_miner_rolled_batched_equals_per_segment_baseline(ground_truth):
-    """`--roll-batch 1` reproduces today's behavior bit-for-bit: the
-    batched tracking sweep and the per-segment loop return identical
-    Results on found, exhausted, and ragged partial-chunk jobs."""
+@pytest.mark.parametrize("roll_batch", [1, 2, 8])
+def test_jax_miner_rolled_matches_brute_force(ground_truth, roll_batch):
+    """Every ``--roll-batch`` size, 1 included, takes the one batched
+    tracking sweep and returns brute force's exact Result on found,
+    exhausted, and ragged partial-chunk jobs."""
     from tpuminter.jax_worker import JaxMiner
 
-    prefix, suffix, branch, hdr80, all_h, h_min, g_min = ground_truth
+    *_, all_h, h_min, g_min = ground_truth
     lo, hi = (1 << NB) + 100, (3 << NB) + 50
-    jobs = [
-        _rolled_request(ground_truth, target=h_min),          # found
-        _rolled_request(ground_truth, target=1),              # exhausted
-        _rolled_request(ground_truth, target=1, lower=lo, upper=hi),
-    ]
-    for req in jobs:
-        base = _drain(JaxMiner(batch=512, roll_batch=1).mine(req))
-        for rb in (2, 8):
-            got = _drain(JaxMiner(batch=512, roll_batch=rb).mine(req))
-            assert (got.found, got.nonce, got.hash_value, got.searched) == (
-                base.found, base.nonce, base.hash_value, base.searched
-            ), (rb, req.lower, req.upper)
+    for target, lower, upper in (
+        (h_min, 0, (ENS << NB) - 1),  # found
+        (1, 0, (ENS << NB) - 1),      # exhausted
+        (1, lo, hi),                  # ragged partial chunk
+    ):
+        req = _rolled_request(ground_truth, target, lower, upper)
+        got = _drain(JaxMiner(batch=512, roll_batch=roll_batch).mine(req))
+        span = all_h[lower:upper + 1]  # all_h[g] is index g's pair
+        wins = [(h, g) for h, g in span if h <= target]
+        if wins:
+            h, g = min(wins, key=lambda p: p[1])
+            want = (True, g, h, g - lower + 1)
+        else:
+            h, g = min(span)
+            want = (False, g, h, upper - lower + 1)
+        assert (got.found, got.nonce, got.hash_value, got.searched) == want, (
+            roll_batch, lower, upper)
 
 
 @pytest.fixture(scope="module")
@@ -491,53 +510,53 @@ def candidate_truth(ground_truth):
     return cands
 
 
-def test_fast_tracking_equivalence_batched_and_unbatched(
-    ground_truth, candidate_truth
+@pytest.mark.parametrize("roll_batch", [1, 4, 8])
+def test_fast_and_tracking_match_brute_force_winner(
+    ground_truth, candidate_truth, roll_batch
 ):
     """Fast/tracking equivalence regression: on an overlapping
     toy-difficulty rolled job — target = the candidate minimum, so every
     winner clears the candidate bar and both paths are exact — the
-    candidate pipeline (`mine_rolled_fast`, TpuMiner's engine) and the
-    tracking sweep (`mine_rolled_tracking`) return identical (found,
-    nonce, hash), batched and unbatched."""
+    candidate pipeline (`mine_rolled_fast`, TpuMiner's engine), the
+    tracking sweep (`mine_rolled_tracking`) and JaxMiner all return brute
+    force's (found, nonce, hash) at every roll_batch size."""
     from tpuminter import rolled
     from tpuminter.jax_worker import JaxMiner
 
     h_c, g_c = min(candidate_truth)
     req = _rolled_request(ground_truth, target=h_c)
     results = {
-        "fast_b4": _drain(rolled.mine_rolled_fast(
-            req, slab=256, roll_batch=4, engine="jnp", cand_bits=8)),
-        "fast_b1": _drain(rolled.mine_rolled_fast(
-            req, slab=256, roll_batch=1, engine="jnp", cand_bits=8)),
-        "tracking_b4": _drain(rolled.mine_rolled_tracking(
-            req, width_cap=256, roll_batch=4)),
-        "tracking_b1": _drain(JaxMiner(batch=256, roll_batch=1).mine(req)),
+        "fast": _drain(rolled.mine_rolled_fast(
+            req, slab=256, roll_batch=roll_batch, engine="jnp", cand_bits=8)),
+        "tracking": _drain(rolled.mine_rolled_tracking(
+            req, width_cap=256, roll_batch=roll_batch)),
+        "jax_miner": _drain(
+            JaxMiner(batch=256, roll_batch=roll_batch).mine(req)),
     }
     for name, r in results.items():
         assert (r.found, r.nonce, r.hash_value) == (True, g_c, h_c), (name, r)
         assert r.nonce >> NB >= 1, name  # the roll actually happened
     # ordered acceptance: everything below the winner was searched. The
-    # sequential baseline stops at exactly the prefix; the batched
-    # pipeline may additionally count in-flight windows above the win
-    # that resolved before it (honest coverage, never less than prefix).
-    assert results["fast_b1"].searched == g_c + 1
-    assert g_c + 1 <= results["fast_b4"].searched <= req.upper + 1
+    # tracking sweep counts exactly the prefix; the candidate pipeline
+    # may additionally count in-flight windows above the win that
+    # resolved before it (honest coverage, never less than prefix).
+    assert results["tracking"].searched == g_c + 1
+    assert results["jax_miner"].searched == g_c + 1
+    assert g_c + 1 <= results["fast"].searched <= req.upper + 1
 
 
-def test_fast_exhausted_candidate_min_batched_matches_baseline(
-    ground_truth, candidate_truth
+@pytest.mark.parametrize("roll_batch", [1, 4, 8])
+def test_fast_exhausted_reports_candidate_min(
+    ground_truth, candidate_truth, roll_batch
 ):
     """Exhausted fast sweeps report the exact range minimum iff a
-    candidate surfaced — and the batched path's global-index candidate
-    bookkeeping agrees with the per-segment baseline."""
+    candidate surfaced — the global-index candidate bookkeeping agrees
+    with brute force at every roll_batch size."""
     from tpuminter import rolled
 
     req = _rolled_request(ground_truth, target=1)  # unbeatable
-    want = min(candidate_truth)
-    for rb in (1, 4):
-        r = _drain(rolled.mine_rolled_fast(
-            req, slab=256, roll_batch=rb, engine="jnp", cand_bits=8))
-        assert not r.found
-        assert (r.hash_value, r.nonce) == want, rb
-        assert r.searched == ENS << NB, rb
+    r = _drain(rolled.mine_rolled_fast(
+        req, slab=256, roll_batch=roll_batch, engine="jnp", cand_bits=8))
+    assert not r.found
+    assert (r.hash_value, r.nonce) == min(candidate_truth)
+    assert r.searched == ENS << NB

@@ -300,6 +300,40 @@ def test_two_tier_rolled_target_end_to_end():
     run(scenario())
 
 
+def test_parent_control_per_segment_stays_flat_as_the_fleet_grows():
+    """The fan-in flattening, measured: the control messages the parent
+    accepts (beacons + results) per settled segment with four miners
+    behind one aggregator stay within 2x of the one-miner figure — the
+    aggregator merges its fleet's beacons into one stream per lease."""
+    ens = 8
+    req = _rolled_request(ens, target=1)
+
+    async def per_segment(n):
+        parent = await Coordinator.create(params=FAST, roll_budget=4)
+        pserve = asyncio.ensure_future(parent.serve())
+        agg = await Aggregator.create(
+            "a1", [("127.0.0.1", parent.port)], params=FAST,
+            beacon_interval=0.05, roll_budget=2,
+        )
+        aserve = asyncio.ensure_future(agg.serve())
+        miners = await _fleet(agg.port, n=n)
+        try:
+            res = await asyncio.wait_for(
+                submit("127.0.0.1", parent.port, req, params=FAST), 60.0
+            )
+            assert not res.found
+            assert parent.stats["hashes"] == ens << NB
+            up = (parent.stats["beacons_accepted"]
+                  + parent.stats["results_accepted"])
+            return up / ens
+        finally:
+            await _teardown(miners, [aserve, pserve], [agg, parent])
+
+    one = run(per_segment(1))
+    four = run(per_segment(4))
+    assert 0 < four <= 2 * one, (one, four)
+
+
 def test_aggregator_crash_mid_lease_is_exactly_once(tmp_path):
     """Kill the aggregator mid-lease (journal crashed, no goodbye),
     restart it over the same WAL with a fresh fleet: the parent

@@ -294,21 +294,21 @@ def test_sweep_program_rejects_bad_shapes():
         sm.resolve_engine("cuda")
 
 
-def test_autotune_cache_is_keyed_separately_from_rolled():
-    """The probe caches per (backend, 'hashcore', engine, ...) in its
-    OWN dict — rolled's cache and key space are untouched, so the two
-    autotunes can never clobber each other."""
-    from tpuminter import rolled
+def test_autotune_cache_is_keyed_separately_from_rolled(monkeypatch):
+    """The probe caches per (backend, 'hashcore', engine, candidates,
+    rows) in its own dict — the only width probe left (the rolled
+    sweep's row width is ``rolled.tile_width(nonce_bits, slab)``) — and
+    a cache hit returns without probing: no timing, no compile."""
+    import tpuminter.search as search
 
-    key = ("cpu-test", "hashcore", "jnp", (256,), 2)
-    sm._autotune_cache[key] = 256
+    def no_probe(*_a, **_k):
+        raise AssertionError("a cache hit must not probe")
+
+    monkeypatch.setattr(search, "timed_call", no_probe)
+    key = ("cpu", "hashcore", "jnp", (256, 512), 2)
+    sm._autotune_cache[key] = 512
     try:
-        assert key not in rolled._autotune_cache
-        # a cache hit returns without probing (no timing, no compile)
-        sm._autotune_cache[
-            ("cpu", "hashcore", "jnp", (256,), 2)
-        ] = 256
-        assert sm.autotune_lane_width("jnp", (256,), rows=2) == 256
+        assert sm.autotune_lane_width("jnp", (256, 512), rows=2) == 512
     finally:
         sm._autotune_cache.pop(key, None)
 

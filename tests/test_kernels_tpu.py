@@ -183,8 +183,8 @@ print("SECTION-OK")
 """,
     # --- batched rolled sweep (ISSUE 7): the per-row-midstate kernel's
     # rows ≡ singleton dynamic-header calls (found flag, first offset,
-    # dynamic valid masking), and TpuMiner's batched fast path ≡ the
-    # roll_batch=1 per-segment baseline on the same fixtures
+    # dynamic valid masking), and TpuMiner's batched fast path lands the
+    # known cross-extranonce winner at a window of one row and of four
     "rolled_batched": r"""
 from tpuminter.kernels import (
     pallas_search_candidates_hdr, pallas_search_candidates_hdr_batch,
@@ -214,28 +214,32 @@ for i in range(3):
 assert int(fb[1]) == 1 and int(bases[1]) + int(ob[1]) == 2804947108
 assert int(fb[2]) == 0  # dynamic valid masking trims row 2's sweep
 
-# TpuMiner batched == per-segment baseline, fast + tracking fixtures
+# TpuMiner at roll_batch 4 and 1: both land the known winner, rehashed
+# with hashlib
 TGT = 0x6d278107d5385a15ebb7b627ad622562f7bc65132eba75b00c300cde
 req7 = Request(job_id=7, mode=PowMode.TARGET, lower=0, upper=(2 << 32) - 1,
                header=GEN.pack(), target=TGT,
                coinbase_prefix=cb_prefix, coinbase_suffix=cb_suffix,
                extranonce_size=4, branch=cb_branch, nonce_bits=32)
-rb = drain(TpuMiner(roll_batch=4).mine(req7))
-r1 = drain(TpuMiner(roll_batch=1).mine(req7))
-assert (rb.found, rb.nonce, rb.hash_value) == (r1.found, r1.nonce, r1.hash_value)
-assert rb.nonce == (1 << 32) + 2804947108
+cb = chain.CoinbaseTemplate(cb_prefix, cb_suffix, 4)
+p76 = chain.rolled_header(GEN.pack(), cb, cb_branch, 1).pack()[:76]
+want7 = chain.hash_to_int(chain.dsha256(p76 + struct.pack("<I", 2804947108)))
+for roll_batch in (4, 1):
+    r = drain(TpuMiner(roll_batch=roll_batch).mine(req7))
+    assert r.found and r.nonce == (1 << 32) + 2804947108, roll_batch
+    assert r.hash_value == want7, roll_batch
 print("SECTION-OK")
 """,
-    # --- shared-compression scheduling (ISSUE 16): the sched=True kernel
-    # body (per-row schedule prefix hoisted via sym.prepare_hdr) returns
-    # bit-identical (found, first_off) rows to the sched=False baseline
-    # on the real chip — winner rows, ragged valids, and padding rows —
-    # and TpuMiner's production default (sched_share on) still lands the
-    # exact cross-extranonce winner of the rolled_batched fixture
-    "sched_share": r"""
-from tpuminter.kernels import pallas_search_candidates_hdr_batch
+    # --- shared-compression scheduling (ISSUE 16): the batched kernel's
+    # shared-schedule body (per-row schedule prefix hoisted via
+    # sym.prepare_hdr) returns the same (found, first_off) rows as the
+    # single-row kernel's full hash_sym_e60_e61 body on the real chip —
+    # winner rows, ragged valids, and padding rows
+    "shared_schedule": r"""
+from tpuminter.kernels import (
+    pallas_search_candidates_hdr, pallas_search_candidates_hdr_batch,
+)
 from tpuminter.ops import merkle
-from tpuminter.tpu_worker import TpuMiner
 rng3 = np.random.RandomState(0)
 cb_prefix = rng3.bytes(41); cb_suffix = rng3.bytes(60)
 cb_branch = tuple(rng3.bytes(32) for _ in range(2))
@@ -246,26 +250,16 @@ mids, tails = roll_b(jnp.zeros(3, jnp.uint32),
 W = 1 << 14
 bases = np.array([100, 2804947108 - 5000, 100], np.uint32)  # row 1 wins
 valids = np.array([W, W, 0], np.uint32)  # row 2: pure padding
-args = (mids, tails, jnp.asarray(bases), jnp.asarray(valids), W, 8, cap1)
-f0, o0 = (np.asarray(x) for x in
-          pallas_search_candidates_hdr_batch(*args, sched=False))
-f1, o1 = (np.asarray(x) for x in
-          pallas_search_candidates_hdr_batch(*args, sched=True))
-assert np.array_equal(f0, f1) and int(f1[1]) == 1
-assert int(o0[1]) == int(o1[1]) == 2804947108 - int(bases[1])
-
-# end-to-end: production default (sched_share on) == off, and both land
-# the known cross-extranonce winner through the whole candidate plane
-TGT = 0x6d278107d5385a15ebb7b627ad622562f7bc65132eba75b00c300cde
-req8 = Request(job_id=8, mode=PowMode.TARGET, lower=0, upper=(2 << 32) - 1,
-               header=GEN.pack(), target=TGT,
-               coinbase_prefix=cb_prefix, coinbase_suffix=cb_suffix,
-               extranonce_size=4, branch=cb_branch, nonce_bits=32)
-r_on = drain(TpuMiner(roll_batch=4).mine(req8))
-r_off = drain(TpuMiner(roll_batch=4, sched_share=False).mine(req8))
-assert (r_on.found, r_on.nonce, r_on.hash_value) == (
-    r_off.found, r_off.nonce, r_off.hash_value)
-assert r_on.nonce == (1 << 32) + 2804947108
+fb, ob = (np.asarray(x) for x in pallas_search_candidates_hdr_batch(
+    mids, tails, jnp.asarray(bases), jnp.asarray(valids), W, 8, cap1))
+for i in range(2):
+    f1, o1 = pallas_search_candidates_hdr(
+        mids[i], tails[i], jnp.uint32(int(bases[i])), W, 8, cap1)
+    assert (int(fb[i]) != 0) == (int(f1) != 0), i
+    if int(fb[i]):
+        assert int(ob[i]) == int(o1), i
+assert int(fb[1]) == 1 and int(ob[1]) == 2804947108 - int(bases[1])
+assert int(fb[2]) == 0  # a padding row never surfaces a candidate
 print("SECTION-OK")
 """,
     # --- pod paths on the real chip (1-chip mesh): the shard_map'd Pallas

@@ -8,9 +8,8 @@ miners across the process seam, a kill -9 + recovery whose re-submitted
 LIVE job lands on a FOREIGN shard process and settles exactly once
 through the cross-shard rebind registry, and one tenant's token bucket
 enforced fleet-wide while its submissions alternate across processes.
-On this one-core image the gates are deterministic invariants (the
-procs-throughput *curve* is bench.py's job, pre-staged for multi-core
-hosts)."""
+The gates are deterministic invariants, not throughput: the
+procs-throughput curve needs a multi-core host."""
 
 import os
 import sys
@@ -153,8 +152,8 @@ def test_two_proc_smoke_rebind_and_quota_drills():
 def test_one_proc_mode_is_the_degenerate_case():
     """procs=1 must behave exactly like a plain coordinator behind the
     process supervisor — no steering (one socket), no drills needed,
-    full throughput path intact. This is the A/B baseline bench.py
-    measures seam overhead against."""
+    full throughput path intact: the baseline that seam overhead is
+    measured against."""
     metrics = run(
         loadgen.run_multiproc(6, 2, 0.9, procs=1, drills=False),
         timeout=120.0,

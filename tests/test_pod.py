@@ -259,32 +259,36 @@ def _rolled_fixture(nb=10, ens=4, seed=5):
     return prefix, suffix, branch, all_h
 
 
-def test_pod_miner_rolled_batched_matches_per_segment_baseline(mesh):
-    """`--roll-batch 1` reproduces today's per-segment pod loop
-    bit-for-bit; the batched sweep (device-major row stripes through
-    build_rolled_sweep) returns the identical Result."""
-    prefix, suffix, branch, _ = _rolled_fixture()
-    nb, ens = 11, 3
-    req = Request(
-        job_id=21, mode=PowMode.TARGET, lower=100,
-        upper=(ens << nb) - 50, header=GEN.pack(),
-        target=chain.bits_to_target(GEN.bits),
-        coinbase_prefix=prefix, coinbase_suffix=suffix,
-        extranonce_size=4, branch=branch, nonce_bits=nb,
+@pytest.mark.parametrize("roll_batch", [1, 6, 8])
+def test_pod_miner_rolled_ragged_range_matches_brute_force(mesh, roll_batch):
+    """Every `--roll-batch` size, 1 included, takes the one batched pod
+    sweep (device-major row stripes through build_rolled_sweep) and
+    returns brute force's Result on a ragged range — the exact first
+    winner, and the exact candidate minimum with full coverage when
+    nothing wins — at the jnp engine's 8-bit candidate bar."""
+    prefix, suffix, branch, all_h = _rolled_fixture()
+    nb, ens = 10, 4
+    lower, upper = 100, (ens << nb) - 50
+    h_c, g_c = min(
+        (h, g) for h, g in all_h if lower <= g <= upper and h >> 248 == 0)
+    miner = PodMiner(
+        mesh=mesh, slab_per_device=64, n_slabs=2, kernel="jnp",
+        roll_batch=roll_batch,
     )
-    results = []
-    for rb in (1, 6):
-        miner = PodMiner(
-            mesh=mesh, slab_per_device=64, n_slabs=2, kernel="jnp",
-            roll_batch=rb,
+    miner._cand_bits = 8
+    for target in (h_c, 1):
+        req = Request(
+            job_id=21, mode=PowMode.TARGET, lower=lower, upper=upper,
+            header=GEN.pack(), target=target,
+            coinbase_prefix=prefix, coinbase_suffix=suffix,
+            extranonce_size=4, branch=branch, nonce_bits=nb,
         )
-        results.append(_drain(miner.mine(req)))
-    base, batched = results
-    assert (base.found, base.nonce, base.hash_value, base.searched) == (
-        batched.found, batched.nonce, batched.hash_value, batched.searched
-    )
-    assert not base.found and base.hash_value == MIN_UNTRACKED
-    assert base.searched == req.upper - req.lower + 1
+        r = _drain(miner.mine(req))
+        assert (r.found, r.nonce, r.hash_value) == (target == h_c, g_c, h_c)
+        if r.found:
+            assert g_c - lower + 1 <= r.searched <= upper - lower + 1
+        else:
+            assert r.searched == upper - lower + 1
 
 
 def test_pod_miner_rolled_batched_finds_exact_first_winner(mesh):

@@ -1150,8 +1150,8 @@ def test_topk_ties_always_rank_the_lowest_global_index(seed, lo, span, k):
 
 
 # ---------------------------------------------------------------------------
-# shared-compression scheduling (ISSUE 16; seeded mirrors in
-# tests/test_sched_share.py since this image lacks hypothesis)
+# shared-compression scheduling (ISSUE 16; the seeded mirrors sit with
+# the layer's other pins)
 # ---------------------------------------------------------------------------
 
 @settings(max_examples=15, deadline=None)
@@ -1161,28 +1161,47 @@ def test_topk_ties_always_rank_the_lowest_global_index(seed, lo, span, k):
     width=st.sampled_from([32, 64]),
     cand_bits=st.sampled_from([8, 32]),
 )
-def test_batched_sweep_sched_share_bit_equal(seed, b, width, cand_bits):
-    """The shared-schedule sweep (``sched=True``) returns the identical
-    ``[found, first_goff]`` pair as the full-digest baseline on any row
-    set — random midstates/tails/bases, ragged valid counts included."""
+def test_batched_sweep_matches_hashlib_scan(seed, b, width, cand_bits):
+    """The shared-schedule sweep returns the ``[found, first_goff]``
+    pair a hashlib scan of the same rolled headers gives, on any row
+    set — random extranonces, bases and caps, ragged valid counts
+    included."""
+    import struct
+
     import numpy as np
     import jax.numpy as jnp
 
     from tpuminter import rolled
+    from tpuminter.ops import merkle
 
     rng = np.random.RandomState(seed)
-    mids = jnp.asarray(rng.randint(0, 1 << 32, (b, 8), dtype=np.uint32))
-    tails = jnp.asarray(rng.randint(0, 1 << 32, (b, 3), dtype=np.uint32))
-    bases = jnp.asarray(rng.randint(0, 1 << 20, b, dtype=np.uint32))
-    valids = jnp.asarray(rng.randint(0, width + 1, b).astype(np.uint32))
-    goffs = jnp.asarray((np.arange(b, dtype=np.uint64) * width)
-                        .astype(np.uint32))
-    cap = jnp.uint32(rng.randint(0, 1 << 32))
-    args = (mids, tails, bases, valids, goffs, cap, width, cand_bits)
-    assert np.array_equal(
-        np.asarray(rolled._jnp_batched_candidate_sweep(*args, False)),
-        np.asarray(rolled._jnp_batched_candidate_sweep(*args, True)),
+    prefix, suffix = rng.bytes(41), rng.bytes(60)
+    hdr80 = chain.GENESIS_HEADER.pack()
+    ens = rng.randint(0, 1 << 32, b, dtype=np.uint32)
+    roll = merkle.make_extranonce_roll_batch(hdr80, prefix, suffix, 4, ())
+    mids, tails = roll(jnp.zeros(b, jnp.uint32), jnp.asarray(ens))
+    bases = rng.randint(0, 1 << 20, b).astype(np.uint32)
+    valids = rng.randint(0, width + 1, b).astype(np.uint32)
+    goffs = (np.arange(b, dtype=np.uint64) * width).astype(np.uint32)
+    cap = int(rng.randint(0, 1 << 32))
+    got = rolled._jnp_batched_candidate_sweep(
+        mids, tails, jnp.asarray(bases), jnp.asarray(valids),
+        jnp.asarray(goffs), jnp.uint32(cap), width, cand_bits,
     )
+
+    cb = chain.CoinbaseTemplate(prefix, suffix, 4)
+    first = 0xFFFFFFFF
+    for en, base, valid, goff in zip(ens, bases, valids, goffs):
+        p76 = chain.rolled_header(hdr80, cb, (), int(en)).pack()[:76]
+        for c in range(int(valid)):
+            h = chain.hash_to_int(
+                chain.dsha256(p76 + struct.pack("<I", int(base) + c)))
+            ok = (h >> 224 == 0 and (h >> 192) & 0xFFFFFFFF <= cap
+                  if cand_bits == 32 else h >> (256 - cand_bits) == 0)
+            if ok:
+                first = min(first, int(goff) + c)
+                break
+    assert np.asarray(got).tolist() == [int(first != 0xFFFFFFFF), first]
 
 
 @settings(max_examples=20, deadline=None)

@@ -812,9 +812,9 @@ def test_pipelined_crash_replay_remines_exactly_the_unsettled_ranges(
 def test_rolled_job_survives_crash_with_batched_path(tmp_path):
     """Rolled e2e through the durable coordinator (ISSUE 7): a rolled
     job at brute-force-checkable difficulty survives a mid-job kill -9
-    + journal replay with the BATCHED sweep on (JaxMiner roll_batch >
-    1), and the reconnecting client gets exactly one answer — the exact
-    global minimum, equal to hashlib brute force."""
+    + journal replay through the batched sweep (JaxMiner
+    roll_batch=3), and the reconnecting client gets exactly one answer —
+    the exact global minimum, equal to hashlib brute force."""
     import struct
 
     import numpy as np

@@ -205,6 +205,42 @@ def test_shipping_builds_a_shadow_equal_to_replaying_the_primary(tmp_path):
     run(scenario(), timeout=30.0)
 
 
+def test_chained_standbys_cost_the_primary_one_stream(tmp_path):
+    """primary → s1 → s2: the primary ships its WAL exactly once
+    (``bytes_shipped`` equals its journal's size) while the tail hop
+    still receives the whole log — the re-ship to s2 comes out of s1's
+    budget, however deep the chain."""
+
+    async def scenario():
+        journal, _ = Journal.open(str(tmp_path / "p.wal"))
+        s2 = await ReplicationStandby.create(
+            str(tmp_path / "s2.wal"), params=FAST)
+        s1 = await ReplicationStandby.create(
+            str(tmp_path / "s1.wal"), params=FAST,
+            chain_to=[("127.0.0.1", s2.port)])
+        runners = [asyncio.ensure_future(s.run()) for s in (s2, s1)]
+        prim = ReplicationPrimary(journal, "127.0.0.1", s1.port,
+                                  params=FAST)
+        prim.start()
+        for jid in range(1, 120):
+            journal.append("job", {"id": jid, "req": _req_obj(jid)})
+        await journal.flush()
+        t0 = time.monotonic()
+        while s2.size < journal.size:
+            assert time.monotonic() - t0 < 15, "chained shipping stalled"
+            await asyncio.sleep(0.02)
+        assert s1.size == s2.size == journal.size
+        assert prim.stats["bytes_shipped"] == journal.size
+        await prim.stop()
+        for r in runners:
+            await _drain(r)
+        for s in (s1, s2):
+            await s.close()
+        await journal.aclose()
+
+    run(scenario(), timeout=30.0)
+
+
 def test_cursor_resume_after_standby_restart_replays_no_record_twice(
     tmp_path,
 ):

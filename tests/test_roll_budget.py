@@ -19,12 +19,20 @@ extranonce units with sub-chunk progress beacons.
   accepted beacons mid-chunk; the journal replays the beacon settles
   as ordinary 0xB7 records, the recovered job re-mines ONLY the
   un-settled suffix, and the resumed fleet still lands the exact min.
+- **Control-plane collapse**: ``scripts/loadgen.py``'s paired rolled
+  A/B (budgeted RollAssigns against global-index chunks, same fleet
+  and clients) passes its own ``rolled_check`` gates at both segment
+  sizes, 2^20 and the production 2^32.
 """
 
 import asyncio
+import os
 import random
 import struct
+import sys
 import time
+
+import pytest
 
 from tpuminter import chain
 from tpuminter.client import submit
@@ -35,6 +43,16 @@ from tpuminter.worker import CpuMiner, run_miner, run_miner_reconnect
 
 from tests.test_e2e import FAST, run
 from tests.test_extranonce import fixture
+
+sys.path.insert(
+    0,
+    os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "scripts",
+    ),
+)
+
+import loadgen  # noqa: E402  (scripts/ is not a package)
 
 NB = 10  # nonce_bits under test (shrunken so a CI sweep rolls)
 
@@ -390,3 +408,19 @@ def test_crash_mid_roll_chunk_replays_only_unsettled(tmp_path):
             await coord.close()
 
     run(scenario(), timeout=120.0)
+
+
+@pytest.mark.parametrize("nonce_bits", [20, 32])
+def test_roll_budget_collapses_control_traffic_per_segment(nonce_bits):
+    """Budgeted RollAssign dispatch sends fewer control messages and
+    fewer wire bytes per settled 2^nonce_bits segment than global-index
+    chunking, by at least ``rolled_check``'s floor (1000x at the
+    production 2^32, 100x at 2^20), with every beacon accepted, beacons
+    at most 5% of results, and no miner lost or result rejected."""
+    m = asyncio.run(loadgen.run_rolled(8, 2, 1.0, nonce_bits=nonce_bits))
+    assert loadgen.rolled_check(m) == []
+    roll, classic = m["roll"], m["classic"]
+    assert roll["ctrl_msgs_per_segment"] < classic["ctrl_msgs_per_segment"]
+    assert roll["wire_bytes_per_segment"] < classic["wire_bytes_per_segment"]
+    if nonce_bits == 32:
+        assert m["collapse_ratio_msgs"] >= 1000.0

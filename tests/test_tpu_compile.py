@@ -86,18 +86,19 @@ def test_search_candidates_compiles_at_production_slab(compiled_for):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("sched", [False, True])
-def test_rolled_batch_kernel_compiles(compiled_for, sched):
-    """The rolled (extranonce) kernel at TpuMiner's roll_batch=8: its
-    per-row SMEM inputs were once blocked as (1, 8) rows, which Mosaic
-    refuses."""
+@pytest.mark.parametrize("rows", [8, 10])
+def test_rolled_batch_kernel_compiles(compiled_for, rows):
+    """The rolled (extranonce) kernel at TpuMiner's roll_batch=8, in
+    both row counts a window dispatches (8 when aligned, 10 padded at
+    job edges — ``rolled.lean_plan``): its per-row SMEM inputs were once
+    blocked as (1, 8) rows, which Mosaic refuses."""
     text = compiled_for(
         lambda mids, tails, bases, valids, cap:
             ksha.pallas_search_candidates_hdr_batch(
-                mids, tails, bases, valids, SLAB, 8, cap, sched=sched
+                mids, tails, bases, valids, SLAB, 8, cap
             ),
-        ((8, 8), U32), ((8, 3), U32), ((8,), U32), ((8,), jnp.int32),
-        ((), U32),
+        ((rows, 8), U32), ((rows, 3), U32), ((rows,), U32),
+        ((rows,), jnp.int32), ((), U32),
     )
     assert "tpu_custom_call" in text
 

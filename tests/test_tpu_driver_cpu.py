@@ -23,7 +23,6 @@ def _bare_tpu_miner(slab=1 << 12, roll_batch=8):
     miner.depth = 2
     miner.exact_min = False
     miner.roll_batch = roll_batch
-    miner.sched_share = True
     miner._scrypt_delegate = None
     miner.lanes = 1
     return miner
@@ -58,12 +57,12 @@ def test_target_fast_driver_runs_on_cpu(monkeypatch):
     assert result.searched == 10_001
 
 
-def test_rolled_fast_driver_runs_on_cpu(monkeypatch):
+@pytest.mark.parametrize("roll_batch", [8, 1])
+def test_rolled_fast_driver_runs_on_cpu(monkeypatch, roll_batch):
     """The production >2^32 driver: window planning × batched roll ×
-    resolve (and the roll_batch=1 per-segment baseline's wiring). This
+    resolve, at the default roll_batch and at a window of one row. This
     exact test catches the r3 resolve NameError class — now with the
-    Pallas engines faked at their tpuminter.rolled seams."""
-    import tpuminter.kernels as kernels
+    Pallas engine faked at its tpuminter.rolled seam."""
     from tpuminter import rolled
 
     monkeypatch.setattr(
@@ -71,9 +70,6 @@ def test_rolled_fast_driver_runs_on_cpu(monkeypatch):
         lambda *a, **k: jnp.asarray(
             np.array([0, 0xFFFFFFFF], np.uint32)
         ),
-    )
-    monkeypatch.setattr(
-        kernels, "pallas_search_candidates_hdr", _clean_kernel
     )
     rng = np.random.RandomState(1)
     nb, ens = 11, 3
@@ -84,12 +80,11 @@ def test_rolled_fast_driver_runs_on_cpu(monkeypatch):
         coinbase_prefix=rng.bytes(41), coinbase_suffix=rng.bytes(60),
         extranonce_size=4, branch=(rng.bytes(32),), nonce_bits=nb,
     )
-    for roll_batch in (8, 1):
-        miner = _bare_tpu_miner(slab=1 << 10, roll_batch=roll_batch)
-        result = _drain(miner._mine_rolled_fast(req))
-        assert not result.found, roll_batch
-        assert result.hash_value == MIN_UNTRACKED, roll_batch
-        assert result.searched == req.upper - req.lower + 1, roll_batch
+    miner = _bare_tpu_miner(slab=1 << 10, roll_batch=roll_batch)
+    result = _drain(miner._mine_rolled_fast(req))
+    assert not result.found
+    assert result.hash_value == MIN_UNTRACKED
+    assert result.searched == req.upper - req.lower + 1
 
 
 def test_target_fast_driver_finds_scripted_candidate(monkeypatch):

@@ -28,7 +28,7 @@ and at most one merged Beacon per lease per ``beacon_interval`` — the
 beacon is computed from the inner job's books (settled prefix = min
 lower bound over its remaining ranges, running best = the inner
 min-fold), so parent-side control messages per settled segment stay
-~constant as the local fleet grows (scripts/bench.py measures it).
+~constant as the local fleet grows (tests/test_federation.py pins it).
 
 Failure matrix (all one-sided, nothing needs distributed agreement):
 
@@ -513,7 +513,7 @@ class Aggregator:
         verifying — the same three places a journal snapshot reads),
         and the claimed pair is the inner min-fold. However many
         workers mine the lease, the parent sees at most one message
-        per tick — the fan-in cost flattening bench.py measures."""
+        per tick — the fan-in cost flattening."""
         for pc, lease in list(self._leases.items()):
             tmpl = self._templates.get(lease.parent_job_id)
             if tmpl is None or not tmpl.rolled or tmpl.mode == PowMode.SCRYPT:

@@ -80,23 +80,6 @@ def _scrypt_step(
     return found, first, midx, digests[midx], digests[first]
 
 
-@jax.jit
-def _rolled_step(
-    mid8: jnp.ndarray, tailw3: jnp.ndarray, nonces: jnp.ndarray,
-    target_words: jnp.ndarray,
-):
-    """Same contract as :func:`_target_step`, but over the dynamic
-    header produced by the on-device extranonce roll — nothing
-    job-specific is baked, so one compile serves every extranonce."""
-    digests = ops.header_digest_dyn(mid8, tailw3, nonces)
-    hw = ops.hash_words_be(digests)
-    ok = ops.lex_le(hw, target_words)
-    found = ok.any()
-    first = jnp.argmax(ok)
-    midx = ops.lex_argmin(hw)
-    return found, first, midx, digests[midx], digests[first]
-
-
 class JaxMiner(Miner):
     """Batched device miner behind the standard Worker interface."""
 
@@ -109,19 +92,12 @@ class JaxMiner(Miner):
         scrypt_batch: int = 256,
         depth: int = 2,
         roll_batch: int = 8,
-        sched_share: bool = True,
     ):
         self.batch = batch
         #: extranonce rows per rolled dispatch (tpuminter.rolled): one
         #: batched roll + one batched sweep per `roll_batch` segments'
-        #: worth of indices, pipelined across segment boundaries.
-        #: 1 = the per-segment A/B baseline (`--roll-batch 1`).
+        #: worth of indices, pipelined across segment boundaries
         self.roll_batch = roll_batch
-        #: ISSUE 16 schedule-sharing layer on the rolled path (for the
-        #: tracking miner this is the roll-side extranonce dedup; the
-        #: sweep-side truncated hash lives in mine_rolled_fast). False
-        #: restores the exact pre-ISSUE-16 dispatches for A/B.
-        self.sched_share = sched_share
         # scrypt's ROMix scratch is 128 KiB per in-flight nonce, so the
         # memory-hard dialect gets its own (much smaller) batch size:
         # scrypt_batch × 128 KiB of V lives on device per step
@@ -308,66 +284,13 @@ class JaxMiner(Miner):
         """Extranonce-rolling TARGET search: the roll (coinbase txid →
         branch fold → merkle root → header midstate) runs ON DEVICE and
         its outputs feed the dynamic-header batch step without ever
-        surfacing to the host (BASELINE.json:9-10). Default: the BATCHED
-        sweep (``tpuminter.rolled.mine_rolled_tracking``) — one roll +
-        one sweep dispatch per ``roll_batch`` rows, pipelined ``depth``
-        deep ACROSS segment boundaries. ``roll_batch=1`` keeps the
-        per-segment loop below as the A/B baseline (bit-equal results,
-        pinned in tests/test_extranonce.py)."""
-        assert req.target is not None
-        if self.roll_batch > 1:
-            from tpuminter import rolled
+        surfacing to the host (BASELINE.json:9-10). The BATCHED sweep
+        (``tpuminter.rolled.mine_rolled_tracking``) — one roll + one
+        sweep dispatch per ``roll_batch`` rows, pipelined ``depth``
+        deep ACROSS segment boundaries."""
+        from tpuminter import rolled
 
-            yield from rolled.mine_rolled_tracking(
-                req, width_cap=self.batch, depth=self.depth,
-                roll_batch=self.roll_batch, sched_share=self.sched_share,
-                progress=self.progress_cb,
-            )
-            return
-        from tpuminter.ops import merkle
-
-        roll = merkle.make_extranonce_roll(
-            req.header, req.coinbase_prefix, req.coinbase_suffix,
-            req.extranonce_size, req.branch,
-        )
-        target_words = jnp.asarray(ops.target_to_words(req.target))
-        best: Optional[Tuple[int, int]] = None  # (hash, global index)
-        for en, base_g, n_lo, n_hi in chain.rolled_segments(
-            req.lower, req.upper, req.nonce_bits
-        ):
-            mid, tailw = roll(jnp.uint32(en >> 32), jnp.uint32(en & 0xFFFFFFFF))
-            for start, valid, nonces in self._batches(n_lo, n_hi):
-                u32 = jnp.asarray(nonces.astype(np.uint32))
-                found, first, midx, min_digest, first_digest = _rolled_step(
-                    mid, tailw, u32, target_words
-                )
-                if bool(found):
-                    first = int(first)
-                    g = base_g | int(nonces[first])
-                    h = ops.digest_to_int(np.asarray(first_digest))
-                    yield Result(
-                        req.job_id, req.mode, g, h, found=True,
-                        searched=min(first + 1, valid)
-                        + ((base_g | start) - req.lower),
-                        chunk_id=req.chunk_id,
-                    )
-                    return
-                midx = int(midx)
-                cand = (
-                    ops.digest_to_int(np.asarray(min_digest)),
-                    base_g | int(nonces[midx]),
-                )
-                if best is None or cand < best:
-                    best = cand
-                if self.progress_cb is not None:
-                    # batches resolve in order: every index through this
-                    # batch's last valid nonce is settled, no winner
-                    self.progress_cb(
-                        (base_g | start) + valid - 1, best[1], best[0]
-                    )
-                yield None
-        yield Result(
-            req.job_id, req.mode, best[1], best[0],
-            found=best[0] <= req.target,
-            searched=req.upper - req.lower + 1, chunk_id=req.chunk_id,
+        yield from rolled.mine_rolled_tracking(
+            req, width_cap=self.batch, depth=self.depth,
+            roll_batch=self.roll_batch, progress=self.progress_cb,
         )

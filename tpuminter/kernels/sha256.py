@@ -421,7 +421,7 @@ def _cand_hdr_kernel(n_tiles, tiles_per_step, n_valid, mask_tail,
     """Early-reject sweep over a header whose midstate and variable tail
     words arrive in SMEM at *runtime* instead of being baked at trace
     time: the consumer of the on-device extranonce roll
-    (``ops.merkle.make_extranonce_roll`` → this kernel, zero host
+    (``ops.merkle.make_extranonce_roll_batch`` → this kernel, zero host
     round-trips per roll, BASELINE.json:9-10) — and, as a bonus, a
     single compiled kernel that serves EVERY header-mining job (no
     ~20-40 s per-job compile).
@@ -495,9 +495,9 @@ def pallas_search_candidates_hdr(
 ):
     """Dynamic-header twin of :func:`pallas_search_candidates`: the
     header midstate (8 u32) and variable tail words (merkle word 7,
-    time, bits) are runtime device values — pass the outputs of
-    ``ops.merkle.make_extranonce_roll`` straight in; they never visit
-    the host. Same return contract: ``(found, first_off)``."""
+    time, bits) are runtime device values — pass one row of
+    ``ops.merkle.make_extranonce_roll_batch``'s outputs straight in;
+    they never visit the host. Same return contract: ``(found, first_off)``."""
     if not 1 <= n <= 1 << 30:
         raise ValueError("n must be in [1, 2^30] (int32 offset domain)")
     if hw1_cap is None:
@@ -524,7 +524,7 @@ def pallas_search_candidates_hdr(
     return row[_FOUND], row[_FIRST_IDX]
 
 
-def _cand_hdr_batch_kernel(n_tiles, tiles_per_step, sched,
+def _cand_hdr_batch_kernel(n_tiles, tiles_per_step,
                            mid_ref, tw_ref, base_ref, lim_ref, cap_ref,
                            out_ref):
     """One grid step = one roll ROW of the batched sweep: identical
@@ -537,9 +537,9 @@ def _cand_hdr_batch_kernel(n_tiles, tiles_per_step, sched,
     bound trims to it (a ``valid == 0`` padding row costs zero sweep
     iterations) and the candidate mask applies it exactly.
 
-    ``sched=True`` (ISSUE 16) hoists the row's shared message-schedule
-    prefix — rounds 0-2 plus the nonce-free parts of w16-w19 — out of
-    the tile loop via ``sym.prepare_hdr``: everything that depends only
+    The row's shared message-schedule prefix (ISSUE 16) — rounds 0-2
+    plus the nonce-free parts of w16-w19 — is hoisted out of the tile
+    loop via ``sym.prepare_hdr``: everything that depends only
     on (midstate, merkle word 7, time, bits) is computed once per grid
     step as 0-d scalars instead of once per tile. Mosaic does not LICM
     scalar work out of ``while_loop`` bodies on its own, so the hoist
@@ -559,7 +559,7 @@ def _cand_hdr_batch_kernel(n_tiles, tiles_per_step, sched,
     cap1 = cap_ref[0]
     limit = lim_ref[r]  # dynamic i32 valid count, NOT a trace constant
     tile_sz = _TILE[0] * LANES
-    prep = sym.prepare_hdr(mid, tail[0], tail[1], tail[2]) if sched else None
+    prep = sym.prepare_hdr(mid, tail[0], tail[1], tail[2])
 
     def cond(carry):
         i, found, _ = carry
@@ -571,12 +571,7 @@ def _cand_hdr_batch_kernel(n_tiles, tiles_per_step, sched,
         for t in range(tiles_per_step):
             offs_i = offs + (i + t) * np.int32(tile_sz)
             nonces = base + jax.lax.bitcast_convert_type(offs_i, jnp.uint32)
-            if sched:
-                e60, e61 = sym.hash_prepared_e60_e61(prep, nonces)
-            else:
-                e60, e61 = sym.hash_sym_e60_e61(
-                    mid, [tail], ops.HEADER_NONCE_POSITIONS, 0, nonces
-                )
+            e60, e61 = sym.hash_prepared_e60_e61(prep, nonces)
             digest6 = sym.add(sym.DIGEST6_BIAS, e61)
             hw1 = sym.xor(
                 sym.shl(sym.and_(digest6, 0x000000FF), 24),
@@ -603,7 +598,7 @@ def _cand_hdr_batch_kernel(n_tiles, tiles_per_step, sched,
     out_ref[0] = jax.lax.bitcast_convert_type(row, jnp.uint32)
 
 
-@partial(jax.jit, static_argnums=(4, 5, 7))
+@partial(jax.jit, static_argnums=(4, 5))
 def pallas_search_candidates_hdr_batch(
     midstates: jnp.ndarray,
     tailws: jnp.ndarray,
@@ -612,7 +607,6 @@ def pallas_search_candidates_hdr_batch(
     width: int,
     tiles_per_step: int = 8,
     hw1_cap: jnp.ndarray | None = None,
-    sched: bool = False,
 ):
     """Batched twin of :func:`pallas_search_candidates_hdr`: a grid over
     ``B`` roll rows, each sweeping up to ``width`` nonces of ITS OWN
@@ -629,10 +623,9 @@ def pallas_search_candidates_hdr_batch(
     candidate), so the caller's cross-row fold is a plain masked min
     over ``global_base[row] + first_offs[row]``.
 
-    ``sched=True`` selects the shared-schedule kernel body (see
-    ``_cand_hdr_batch_kernel``): per-row scalar schedule prefix hoisted
-    out of the tile loop, identical results. ``False`` is the exact
-    pre-ISSUE-16 kernel — the bit-for-bit A/B baseline.
+    Each row hashes through the shared-schedule kernel body (see
+    ``_cand_hdr_batch_kernel``): its scalar schedule prefix is hoisted
+    out of the tile loop.
     """
     if not 1 <= width <= 1 << 30:
         raise ValueError("width must be in [1, 2^30] (int32 offset domain)")
@@ -645,7 +638,7 @@ def pallas_search_candidates_hdr_batch(
         hw1_cap.astype(jnp.uint32) ^ jnp.uint32(0x80000000), jnp.int32
     )
     summary = pl.pallas_call(
-        partial(_cand_hdr_batch_kernel, n_tiles, tiles_per_step, sched),
+        partial(_cand_hdr_batch_kernel, n_tiles, tiles_per_step),
         out_shape=jax.ShapeDtypeStruct((b,) + _TILE, jnp.uint32),
         grid=(b,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 5,

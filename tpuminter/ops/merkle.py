@@ -10,7 +10,7 @@ miners do this on the host. Here the whole chain
     extranonce → coinbase txid → branch fold → merkle root
                → header midstate + variable tail words
 
-runs as ONE jitted device program (:func:`make_extranonce_roll`), so a
+runs as ONE jitted device program (:func:`make_extranonce_roll_batch`), so a
 >2^32 search never ships header bytes from the host: the roll's
 ``(midstate, tail_words)`` outputs stay on device and feed either the
 jnp dynamic-header hash (``ops.sha256.header_digest_dyn``) or the
@@ -22,7 +22,6 @@ The roll is **batch-shaped**: :func:`make_extranonce_roll_batch` rolls
 midstates + (B, 3) tail batches`` — which is what lets a batched sweep
 (``tpuminter.rolled``) cover many extranonce segments per dispatch
 instead of re-entering host orchestration at every segment boundary.
-The scalar :func:`make_extranonce_roll` is the same core at B-of-one.
 :func:`roll_batch_deduped` layers the shared-compression discipline on
 top (ISSUE 16): rows of a window that carry the same extranonce share
 ONE roll evaluation, forked per row by a device gather.
@@ -52,7 +51,6 @@ from tpuminter.chain import HEADER_SIZE, SHA256_H0
 from tpuminter.ops import sha256 as ops
 
 __all__ = [
-    "make_extranonce_roll",
     "make_extranonce_roll_batch",
     "roll_batch_deduped",
 ]
@@ -143,50 +141,6 @@ def _build_roll(
     return roll
 
 
-def make_extranonce_roll(
-    header80: bytes,
-    coinbase_prefix: bytes,
-    coinbase_suffix: bytes,
-    extranonce_size: int,
-    branch: Sequence[bytes],
-) -> Callable[[jnp.ndarray, jnp.ndarray], Tuple[jnp.ndarray, jnp.ndarray]]:
-    """Compile the device roll for one job.
-
-    Returns ``roll(en_hi_u32, en_lo_u32) -> (midstate (8,) u32,
-    tail_words (3,) u32)``: the SHA-256 state after the rolled header's
-    first 64 bytes, and the header tail words ``(merkle word 7, time,
-    bits)`` — exactly what ``ops.header_digest_dyn`` and the dynamic
-    Pallas kernel consume. ``header80``'s merkle-root field is ignored
-    (it is what the roll recomputes); version/prev/time/bits are baked
-    as constants. ≡ ``ops.header_template(chain.rolled_header(...).
-    pack())``'s ``midstate``/``tail_words()`` for every extranonce
-    (pinned by tests/test_extranonce.py).
-    """
-    return _cached_scalar_roll(
-        header80, coinbase_prefix, coinbase_suffix, extranonce_size,
-        tuple(branch),
-    )
-
-
-@lru_cache(maxsize=32)
-def _cached_scalar_roll(header80, coinbase_prefix, coinbase_suffix,
-                        extranonce_size, branch):
-    """Jitted rolls are cached by their job constants: a re-submitted
-    (or re-benchmarked) job must reuse the compiled program instead of
-    re-tracing — a fresh ``jax.jit`` wrapper per call is a fresh jit
-    cache entry, measured ~0.6 s per re-trace on the CPU engine."""
-    batch = _build_roll(
-        header80, coinbase_prefix, coinbase_suffix, extranonce_size, branch
-    )
-
-    @jax.jit
-    def roll(en_hi: jnp.ndarray, en_lo: jnp.ndarray):
-        mid, tail = batch(en_hi.reshape(1), en_lo.reshape(1))
-        return mid[0], tail[0]
-
-    return roll
-
-
 def make_extranonce_roll_batch(
     header80: bytes,
     coinbase_prefix: bytes,
@@ -196,13 +150,21 @@ def make_extranonce_roll_batch(
     *,
     jit: bool = True,
 ) -> Callable[[jnp.ndarray, jnp.ndarray], Tuple[jnp.ndarray, jnp.ndarray]]:
-    """Batched twin of :func:`make_extranonce_roll`: ONE device call
-    rolls a whole extranonce batch — ``roll(en_hi (B,), en_lo (B,)) ->
-    (midstates (B, 8) u32, tail_words (B, 3) u32)``, row ``i`` ≡ the
-    scalar roll of ``(en_hi[i], en_lo[i])`` (pinned bit-equal by
-    tests/test_extranonce.py). This is the producer side of the batched
-    rolled sweep (``tpuminter.rolled``): B segment midstates per
-    dispatch instead of one host-orchestrated roll per segment.
+    """Compile the device roll for one job: ONE device call rolls a
+    whole extranonce batch — ``roll(en_hi (B,), en_lo (B,)) ->
+    (midstates (B, 8) u32, tail_words (B, 3) u32)``. Row ``i`` is the
+    SHA-256 state after the rolled header's first 64 bytes and the
+    header tail words ``(merkle word 7, time, bits)`` for extranonce
+    ``(en_hi[i] << 32) | en_lo[i]`` — exactly what
+    ``ops.header_digest_dyn`` and the dynamic Pallas kernels consume.
+    ``header80``'s merkle-root field is ignored (it is what the roll
+    recomputes); version/prev/time/bits are baked as constants. ≡
+    ``ops.header_template(chain.rolled_header(...).pack())``'s
+    ``midstate``/``tail_words()`` for every extranonce (pinned
+    bit-equal by tests/test_extranonce.py). This is the producer side
+    of the batched rolled sweep (``tpuminter.rolled``): B segment
+    midstates per dispatch instead of one host-orchestrated roll per
+    segment.
 
     ``jit=False`` returns the traceable body for callers embedding the
     roll in their own jitted program.

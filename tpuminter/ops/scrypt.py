@@ -177,14 +177,13 @@ def romix(x: jnp.ndarray, n_log2: int) -> jnp.ndarray:
     # regresses); kept at 1 on the CPU mesh where CI would pay a doubled
     # scan-body compile for zero benefit (the knob only reschedules; the
     # math is identical). A fully-fused Pallas ROMix was prototyped and
-    # rejected on measurement (scripts/romix_pallas_probe.py), and round
-    # 5 measured SIX fused relayout+xor+salsa designs — pallas kernels
-    # on every byte layout the gather can emit (incl. its native
-    # sublane-interleaved tiles), a plane-major element gather, and an
-    # MXU identity-dot transpose — all ~650 µs/step or worse: the walk
-    # is floor-bound by the TPU gather emitter's custom-call/relayout
-    # boundary, not by this scan body. See PERF.md's scrypt section and
-    # scripts/walk_*_probe.py.
+    # rejected on measurement, and round 5 measured SIX fused
+    # relayout+xor+salsa designs — pallas kernels on every byte layout
+    # the gather can emit (incl. its native sublane-interleaved tiles),
+    # a plane-major element gather, and an MXU identity-dot transpose —
+    # all ~650 µs/step or worse on v5e: the walk is floor-bound by the
+    # TPU gather emitter's custom-call/relayout boundary, not by this
+    # scan body. See PERF.md's scrypt section.
     unroll = 2 if jax.default_backend() != "cpu" else 1
 
     def fill(carry, _):

@@ -345,8 +345,8 @@ def header_digest_dyn(
     sweeps every row of a ``make_extranonce_roll_batch`` output.
 
     This is the hash the on-device extranonce roll feeds
-    (``ops.merkle.make_extranonce_roll`` produces exactly this
-    ``(midstate, tail_words)`` pair from an extranonce, BASELINE.json:
+    (``ops.merkle.make_extranonce_roll_batch`` produces exactly this
+    ``(midstate, tail_words)`` pair for each extranonce, BASELINE.json:
     9-10): one compiled program serves every extranonce — and every
     header-mining job — because nothing job-specific is baked in.
     ``tailw3`` is ``(merkle_root word 7, time word, bits word)``, the

@@ -445,13 +445,10 @@ def lane_sweep(
 
 
 # ---------------------------------------------------------------------------
-# width autotune: one-shot cached probe, hashcore's OWN cache
+# width autotune: one-shot cached probe
 # ---------------------------------------------------------------------------
 
 #: (backend, workload, engine, candidates, rows) -> winning width.
-#: Deliberately a separate dict from rolled._autotune_cache — the key
-#: spaces overlap in spirit (both are per-backend width probes) and a
-#: shared cache would let one workload's winner shadow the other's.
 _autotune_cache: Dict[Tuple, int] = {}
 
 
@@ -462,7 +459,7 @@ def autotune_lane_width(
     rows: int = ROWS,
     reps: int = 3,
 ) -> int:
-    """``rolled.autotune_width``'s shape, retargeted: time the fmin
+    """One-shot width probe: time the fmin
     sweep program over dummy data at each candidate width, keep the
     best per-index rate, cache for the process lifetime. The probe
     compiles each candidate once — the winner's program is therefore

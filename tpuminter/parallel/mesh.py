@@ -294,7 +294,6 @@ def build_candidate_sweep(
     n_slabs: int,
     tiles_per_step: int = 8,
     kernel: str = "auto",
-    dynamic_header: bool = False,
 ) -> Callable:
     """Compile the PRODUCTION pod-wide candidate sweep (BASELINE.json:5;
     VERDICT r2 #3): the same early-reject candidate test the single-chip
@@ -336,13 +335,7 @@ def build_candidate_sweep(
     candidate kernel — the production TPU path), ``"jnp"`` (same
     candidate condition via the jnp ops — compiles on the CPU mesh, the
     CI path), or ``"auto"`` (pallas iff the default backend is not
-    CPU).
-
-    ``dynamic_header=True`` builds the extranonce-roll consumer
-    (BASELINE.json:9-10 at pod scale): the sweep takes two extra
-    replicated args ``(midstate8, tailw3)`` — the on-device roll's
-    outputs — instead of baking ``template``, so ONE compiled pod
-    program serves every extranonce (and every header-mining job).
+    CPU). Rolled jobs take :func:`build_rolled_sweep` instead.
     """
     if kernel == "auto":
         kernel = "jnp" if jax.default_backend() == "cpu" else "pallas"
@@ -353,30 +346,20 @@ def build_candidate_sweep(
     umax = np.uint32(0xFFFFFFFF)
 
     if kernel == "pallas":
-        from tpuminter.kernels import (
-            pallas_search_candidates,
-            pallas_search_candidates_hdr,
-        )
+        from tpuminter.kernels import pallas_search_candidates
 
-        def slab_sweep(base, cap_biased, hdr):
+        def slab_sweep(base, cap_biased):
             cap = jax.lax.bitcast_convert_type(
                 cap_biased, jnp.uint32
             ) ^ jnp.uint32(0x80000000)
-            if dynamic_header:
-                return pallas_search_candidates_hdr(
-                    hdr[0], hdr[1], base, slab, tiles_per_step, cap
-                )
             return pallas_search_candidates(
                 template, base, slab, tiles_per_step, cap
             )
     else:
 
-        def slab_sweep(base, cap_biased, hdr):
+        def slab_sweep(base, cap_biased):
             nonces = base + jnp.arange(slab, dtype=jnp.uint32)
-            if dynamic_header:
-                digests = ops.header_digest_dyn(hdr[0], hdr[1], nonces)
-            else:
-                digests = ops.double_sha256_header_batch(template, nonces)
+            digests = ops.double_sha256_header_batch(template, nonces)
             hw = ops.hash_words_be(digests)
             hw1b = jax.lax.bitcast_convert_type(
                 hw[:, 1] ^ jnp.uint32(0x80000000), jnp.int32
@@ -384,7 +367,7 @@ def build_candidate_sweep(
             ok = (hw[:, 0] == 0) & (hw1b <= cap_biased)
             return ok.any().astype(jnp.uint32), jnp.argmax(ok).astype(jnp.uint32)
 
-    def per_device(start, cap_biased, *hdr):
+    def per_device(start, cap_biased):
         d = lax.axis_index(AXIS).astype(jnp.uint32)
 
         def cond(state):
@@ -395,7 +378,7 @@ def build_candidate_sweep(
             b, _, _ = state
             slab_idx = b * np.uint32(n_dev) + d
             base = start + slab_idx * np.uint32(slab)
-            f, off = slab_sweep(base, cap_biased, hdr)
+            f, off = slab_sweep(base, cap_biased)
             local = (f > 0) & (off < slab)
             cand_off = slab_idx * np.uint32(slab) + off.astype(jnp.uint32)
             # pod-wide or-reduce over ICI: the early-exit signal; pmin
@@ -410,15 +393,14 @@ def build_candidate_sweep(
         )
         return found, first, b
 
-    n_in = 4 if dynamic_header else 2
     sharded = _shard_map(
-        per_device, mesh, in_specs=(P(),) * n_in, out_specs=(P(), P(), P())
+        per_device, mesh, in_specs=(P(), P()), out_specs=(P(), P(), P())
     )
 
-    def pod_candidate_sweep(*args):
+    def pod_candidate_sweep(start, cap_biased):
         # the program's own name in a device trace (``XLA Modules`` shows
         # ``jit_pod_candidate_sweep``), apart from the other pod programs
-        return sharded(*args)
+        return sharded(start, cap_biased)
 
     return jax.jit(pod_candidate_sweep)
 

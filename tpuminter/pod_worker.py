@@ -17,7 +17,7 @@ Dialect routing:
   in-kernel early exit per chip and at most ``n_slabs`` ICI rounds —
   the host only verifies the ~1-per-2^32 candidates. Rolled jobs use
   the dynamic-header sweep (one compile for every extranonce) with the
-  roll itself on device (``ops.merkle.make_extranonce_roll``).
+  roll itself on device (``ops.merkle.make_extranonce_roll_batch``).
 - **MIN** runs the fused Pallas toy kernel per chip under ``shard_map``
   (``parallel.build_min_sweep_pallas`` — the single-chip TpuMiner's
   engine at pod scale) with the argmin fold over ICI; the CPU mesh (CI)
@@ -184,8 +184,7 @@ class PodMiner(Miner):
         self.spmd_leader = spmd_leader
         self._open_inner = None  # leader's in-progress chunk generator
         #: extranonce rows per rolled dispatch (tpuminter.rolled),
-        #: rounded up to a whole number of per-device stripes; 1 = the
-        #: per-segment A/B baseline
+        #: rounded up to a whole number of per-device stripes
         self.roll_batch = roll_batch
         #: jnp-engine candidate-bar seam (tpuminter.rolled docstring):
         #: production 32; tests shrink it so CI-sized rolled spaces
@@ -193,7 +192,6 @@ class PodMiner(Miner):
         self._cand_bits = 32
         self._rolled_sweeps = {}  # (width, rows) -> compiled pod sweep
         self._sweep_static = None  # compiled pod programs, built lazily
-        self._sweep_dyn = None
         self._scrypt_sweep = None
         self._exact_sweep = None
         self._exact_template = None
@@ -358,17 +356,13 @@ class PodMiner(Miner):
         ``parallel.build_rolled_sweep`` dispatches — device-major
         interleaved roll rows with stripe-synchronous ICI early exit —
         fed by one batched roll call per window. The pod stops
-        re-entering host orchestration 2^ext_bits times per chunk;
-        ``roll_batch=1`` keeps the per-segment loop as the A/B
-        baseline."""
+        re-entering host orchestration 2^ext_bits times per chunk."""
         assert req.header is not None and req.target is not None
-        if self.roll_batch <= 1:
-            yield from self._mine_rolled_segmented(req)
-            return
         from tpuminter import rolled
         from tpuminter.ops import merkle
         from tpuminter.parallel import build_rolled_sweep
 
+        rolled._check_roll_batch(self.roll_batch)
         width = rolled.tile_width(req.nonce_bits, self.slab_per_device)
         rows = -(-(self.roll_batch + 2) // self.n_dev) * self.n_dev
         window = (rows - 2) * width
@@ -417,78 +411,6 @@ class PodMiner(Miner):
             yield None
         yield self._fast_result(req, search)
 
-    def _mine_rolled_segmented(self, req: Request) -> Iterator[Optional[Result]]:
-        """The pre-batching baseline (``roll_batch=1``): one scalar roll
-        + one drained ``CandidateSearch`` per extranonce segment over
-        the singleton dynamic-header pod sweep."""
-        from tpuminter.ops import merkle
-
-        if self._sweep_dyn is None:
-            self._sweep_dyn = build_candidate_sweep(
-                self.mesh, None,
-                slab_per_device=self.slab_per_device,
-                n_slabs=self.n_slabs, tiles_per_step=self.tiles_per_step,
-                kernel=self.kernel, dynamic_header=True,
-            )
-        roll = merkle.make_extranonce_roll(
-            req.header, req.coinbase_prefix, req.coinbase_suffix,
-            req.extranonce_size, req.branch,
-        )
-        cb = chain.CoinbaseTemplate(
-            req.coinbase_prefix, req.coinbase_suffix, req.extranonce_size
-        )
-        cap = _biased_cap(req.target)
-        searched = 0
-        candidates = []  # (global index, hash)
-        for en, base_g, n_lo, n_hi in chain.rolled_segments(
-            req.lower, req.upper, req.nonce_bits
-        ):
-            mid, tailw = roll(jnp.uint32(en >> 32), jnp.uint32(en & 0xFFFFFFFF))
-
-            def sweep_fn(base, _mid=mid, _tailw=tailw):
-                return self._sweep_dyn(base, cap, _mid, _tailw)
-
-            prefix_cache: list = []
-
-            def verify(nonce: int, _en=en, _cache=prefix_cache):
-                if not _cache:
-                    _cache.append(
-                        chain.rolled_header(req.header, cb, req.branch, _en)
-                        .pack()[:76]
-                    )
-                h = chain.hash_to_int(
-                    chain.dsha256(_cache[0] + struct.pack("<I", nonce))
-                )
-                return h <= req.target, h
-
-            search = self._pod_search(n_lo, n_hi, sweep_fn, verify)
-            for _ in search.events():
-                yield None
-            out = search.outcome
-            candidates += [(base_g | n, h) for n, h in out.candidates]
-            if out.found:
-                yield Result(
-                    req.job_id, req.mode, base_g | out.nonce, out.hash_value,
-                    found=True, searched=searched + out.searched,
-                    chunk_id=req.chunk_id,
-                )
-                return
-            searched += out.searched
-            if self.progress_cb is not None and (base_g | n_hi) < req.upper:
-                # segment-boundary granularity: everything up to this
-                # segment's end is settled winner-free
-                bh, bg = min(
-                    ((h, g) for g, h in candidates),
-                    default=(MIN_UNTRACKED, req.lower),
-                )
-                self.progress_cb(base_g | n_hi, bg, bh)
-        best = min(((h, g) for g, h in candidates), default=None)
-        hash_value, nonce = best if best else (MIN_UNTRACKED, req.lower)
-        yield Result(
-            req.job_id, req.mode, nonce, hash_value, found=False,
-            searched=searched, chunk_id=req.chunk_id,
-        )
-
     def _fast_result(self, req: Request, search: CandidateSearch) -> Result:
         out = search.outcome
         if out.found:
@@ -519,8 +441,8 @@ class PodMiner(Miner):
 
     @property
     def exact_min_span(self) -> int:
-        """Nonces one exact-min device call covers. Exposed so bench/
-        test code (and ``_mine_target_exact`` itself) never re-derives
+        """Nonces one exact-min device call covers. Exposed so test
+        code (and ``_mine_target_exact`` itself) never re-derives
         the formula — the loop stride and the compiled sweep's coverage
         must come from one place or they drift apart silently. Engine-
         dependent: the Pallas sweep folds a whole slab per chip per

@@ -250,7 +250,7 @@ class Request:
     ``extranonce_size`` little-endian extranonce bytes, folded up
     ``branch``. ``nonce_bits`` is 32 in production; tests shrink it so a
     roll happens within a tractable sweep. Workers perform the roll on
-    device (``ops.merkle.make_extranonce_roll``).
+    device (``ops.merkle.make_extranonce_roll_batch``).
 
     ``client_key`` is a durable client identity (any opaque string the
     client chooses once and reuses across reconnects). Connection ids

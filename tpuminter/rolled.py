@@ -28,24 +28,24 @@ module makes the rolled sweep batch- and pipeline-native end to end:
   min-fold/candidate bookkeeping is keyed by global index exactly as
   before.
 
-``roll_batch=1`` keeps the per-segment loop reachable as the A/B
-baseline (:func:`mine_rolled_fast` routes to the segmented form — the
-pre-batching production path, bit-for-bit).
+``roll_batch`` is a size, never a mode: ``roll_batch=1`` is a window of
+one row (plus the two rows a misaligned window may straddle), through
+the same roll, sweep and search as any other size.
 
 The ``engine`` seam ("pallas" on TPU, "jnp" on the CPU mesh) is what
-lets CI pin the whole batched path — and bench.py measure the A/B —
-without a chip. ``cand_bits`` scales the candidate bar for tests ONLY:
-production keeps 32 (top hash word zero + the hash-word-1 cap, the
-necessary condition at every real difficulty); tests shrink it so a
-CI-sized space contains candidates and the full surfacing/re-issue/
-min-fold machinery gets exercised at toy difficulty.
+lets CI pin the whole batched path without a chip. ``cand_bits`` scales
+the candidate bar for tests ONLY: production keeps 32 (top hash word
+zero + the hash-word-1 cap, the necessary condition at every real
+difficulty); tests shrink it so a CI-sized space contains candidates
+and the full surfacing/re-issue/min-fold machinery gets exercised at
+toy difficulty.
 """
 
 from __future__ import annotations
 
 import struct
 from functools import lru_cache, partial
-from typing import Callable, Dict, Iterator, NamedTuple, Optional, Tuple, Union
+from typing import Callable, Iterator, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,8 +55,7 @@ from tpuminter import chain
 from tpuminter.ops import sha256 as ops
 from tpuminter.protocol import MIN_UNTRACKED, Request, Result
 from tpuminter.search import (
-    CandidateSearch, pack_handle, pipeline_spans, pull, resolve_handle,
-    timed_call,
+    CandidateSearch, pipeline_spans, pull, resolve_handle,
 )
 
 __all__ = [
@@ -67,7 +66,6 @@ __all__ = [
     "rolled_verifier",
     "mine_rolled_fast",
     "mine_rolled_tracking",
-    "autotune_width",
     "ProgressFn",
     "report_search_progress",
 ]
@@ -232,9 +230,9 @@ def _resolve_engine(engine: str) -> str:
     return engine
 
 
-def _count(counters: Optional[Dict[str, int]], key: str) -> None:
-    if counters is not None:
-        counters[key] = counters.get(key, 0) + 1
+def _check_roll_batch(roll_batch: int) -> None:
+    if roll_batch < 1:
+        raise ValueError("roll_batch must be >= 1")
 
 
 # ---------------------------------------------------------------------------
@@ -274,51 +272,41 @@ def _jnp_candidate_ok_sched(mid, tw, nonces, cap, cand_bits: int):
 
 def _jnp_batched_candidate_sweep(
     mids, tails, bases, valids, goffs, cap, width: int, cand_bits: int,
-    sched: bool = False,
 ):
     """Dispatch :func:`_jnp_batched_sweep` to the program that runs on
     this backend: XLA:CPU's fusion pass merges the unrolled rounds of
-    the ``sched=True`` hash into one loop fusion that recomputes every
+    the shared-schedule hash into one loop fusion that recomputes every
     shared subexpression per element, so its run time grows
     exponentially with the round count (jaxlib 0.9: 24 rounds run in
     2 s, 62 did not finish in 300 s). Fusion is switched off for that
     one program on CPU only."""
     sweep = _jnp_batched_sweep
-    if sched and jax.default_backend() == "cpu":
+    if jax.default_backend() == "cpu":
         sweep = _jnp_batched_sweep_unfused
-    return sweep(mids, tails, bases, valids, goffs, cap, width, cand_bits,
-                 sched)
+    return sweep(mids, tails, bases, valids, goffs, cap, width, cand_bits)
 
 
 def _jnp_batched_sweep(
     mids, tails, bases, valids, goffs, cap, width: int, cand_bits: int,
-    sched: bool = False,
 ):
     """jnp mirror of ``pallas_search_candidates_hdr_batch`` + the
     cross-row fold, one program: (R, width) nonces under R dynamic
     headers → ``[found, first_global_off]``. Compiled once per (width,
-    cand_bits, sched) — nothing job-specific is baked.
+    cand_bits) — nothing job-specific is baked.
 
     Rows run SEQUENTIALLY inside the program (``lax.scan``), mirroring
     the Pallas kernel's grid-over-rows: on the CPU engine a flat
     (R·width)-lane program blows the cache and costs ~50% more per hash
     (measured: 3.15 → 4.86 µs at 8×256), while per-row working sets
-    stay cache-sized and the dispatch count still drops ~B×.
-
-    ``sched=True`` swaps the per-row hash for the shared-schedule
-    truncated form (:func:`_jnp_candidate_ok_sched`): identical fold,
-    identical booleans, measured ~34× per-hash on this CPU at 8×256
-    (PERF.md §Round 14). ``False`` is the bit-for-bit A/B baseline —
-    the exact pre-ISSUE-16 program."""
+    stay cache-sized and the dispatch count still drops ~B×. Each row
+    hashes through the shared-schedule truncated form
+    (:func:`_jnp_candidate_ok_sched`, measured ~34× per hash over the
+    full digest on CPU at 8×256, PERF.md §Round 14)."""
     col = jnp.arange(width, dtype=jnp.uint32)
 
     def row(carry, x):
         mid, tw, base, valid, goff = x
-        if sched:
-            ok = _jnp_candidate_ok_sched(mid, tw, base + col, cap, cand_bits)
-        else:
-            digests = ops.header_digest_dyn(mid, tw, base + col)
-            ok = _jnp_candidate_ok(digests, cap, cand_bits)
+        ok = _jnp_candidate_ok_sched(mid, tw, base + col, cap, cand_bits)
         ok = ok & (col < valid)
         g = jnp.where(ok, goff + col, _UMAX)
         found, first = carry
@@ -332,102 +320,27 @@ def _jnp_batched_sweep(
 
 
 _jnp_batched_sweep_unfused = jax.jit(
-    _jnp_batched_sweep, static_argnums=(6, 7, 8),
+    _jnp_batched_sweep, static_argnums=(6, 7),
     compiler_options={"xla_disable_hlo_passes": "fusion"},
 )
-_jnp_batched_sweep = jax.jit(_jnp_batched_sweep, static_argnums=(6, 7, 8))
+_jnp_batched_sweep = jax.jit(_jnp_batched_sweep, static_argnums=(6, 7))
 
 
-@partial(jax.jit, static_argnums=(6, 7, 8))
+@partial(jax.jit, static_argnums=(6, 7))
 def _pallas_batched_candidate_sweep(
     mids, tails, bases, valids, goffs, cap, width: int, tiles_per_step: int,
-    sched: bool = False,
 ):
     """Pallas engine: the batched dynamic-header kernel (one launch
-    grids over roll rows) + the same cross-row fold. ``sched=True``
-    selects the shared-schedule kernel variant (per-row scalar prefix
-    hoisted out of the tile loop via ``sym.prepare_hdr``)."""
+    grids over roll rows, each row's schedule prefix hoisted out of the
+    tile loop via ``sym.prepare_hdr``) + the same cross-row fold."""
     from tpuminter.kernels import pallas_search_candidates_hdr_batch
 
     founds, firsts = pallas_search_candidates_hdr_batch(
-        mids, tails, bases, valids, width, tiles_per_step, cap, sched=sched
+        mids, tails, bases, valids, width, tiles_per_step, cap
     )
     ok = founds != 0
     g = jnp.where(ok, goffs + firsts, _UMAX)
     return jnp.stack([ok.any().astype(jnp.uint32), jnp.min(g)])
-
-
-@partial(jax.jit, static_argnums=(4, 5))
-def _jnp_segment_candidate_sweep(mid, tail, base, cap, width: int, cand_bits: int):
-    """Singleton (per-segment baseline) jnp candidate sweep: one row,
-    no valid masking — the ``CandidateSearch`` oversweep contract covers
-    hits past the logical end."""
-    nonces = base + jnp.arange(width, dtype=jnp.uint32)
-    digests = ops.header_digest_dyn(mid, tail, nonces)
-    ok = _jnp_candidate_ok(digests, cap, cand_bits)
-    off = jnp.where(ok, jnp.arange(width, dtype=jnp.uint32), _UMAX)
-    return jnp.stack([ok.any().astype(jnp.uint32), jnp.min(off)])
-
-
-# ---------------------------------------------------------------------------
-# width autotune: one-shot cached startup probe
-# ---------------------------------------------------------------------------
-
-#: (backend, candidates, cand_bits, sched_share, rows) -> winning width.
-#: Process-lifetime cache: the probe costs one compile + a few dispatches
-#: per candidate width, so it runs at most once per configuration.
-_autotune_cache: Dict[Tuple, int] = {}
-
-
-def autotune_width(
-    candidates: Tuple[int, ...] = (128, 256, 512, 1024),
-    *,
-    cand_bits: int = 32,
-    sched_share: bool = True,
-    rows: int = 8,
-    reps: int = 3,
-) -> int:
-    """One-shot startup probe: time :func:`_jnp_batched_candidate_sweep`
-    over dummy data at each candidate ``width`` and return the one with
-    the best per-hash rate. Cached per (backend, candidates, cand_bits,
-    sched_share, rows) for the life of the process — callers pay the
-    probe once, then every ``width="auto"`` miner reads the dict.
-
-    The probe is deliberately tiny (min-of-``reps`` after one warm
-    call): it ranks widths against each other on THIS backend rather
-    than measuring absolute throughput, so a handful of dispatches is
-    enough to separate cache-sized from cache-blowing row widths. The
-    explicit ``width=`` knob on :func:`mine_rolled_fast` remains the
-    A/B override — autotune never forces a choice on callers that pin
-    one."""
-    key = (jax.default_backend(), tuple(candidates), cand_bits,
-           bool(sched_share), rows)
-    hit = _autotune_cache.get(key)
-    if hit is not None:
-        return hit
-
-    rng = np.random.RandomState(0)
-    cap = jnp.uint32(0)
-    best_width, best_rate = candidates[0], -1.0
-    for width in candidates:
-        mids = jnp.asarray(rng.randint(0, 1 << 32, (rows, 8), dtype=np.uint32))
-        tails = jnp.asarray(rng.randint(0, 1 << 32, (rows, 3), dtype=np.uint32))
-        bases = jnp.asarray(rng.randint(0, 1 << 20, rows, dtype=np.uint32))
-        valids = jnp.asarray(np.full(rows, width, np.uint32))
-        goffs = jnp.asarray((np.arange(rows, dtype=np.uint64) * width)
-                            .astype(np.uint32))
-        args = (mids, tails, bases, valids, goffs, cap, width, cand_bits,
-                sched_share)
-        _jnp_batched_candidate_sweep(*args).block_until_ready()  # compile
-        dt = min(
-            timed_call(_jnp_batched_candidate_sweep, args)
-            for _ in range(max(1, reps))
-        )
-        rate = rows * width / dt
-        if rate > best_rate:
-            best_width, best_rate = width, rate
-    _autotune_cache[key] = best_width
-    return best_width
 
 
 # ---------------------------------------------------------------------------
@@ -457,58 +370,32 @@ def mine_rolled_fast(
     engine: str = "auto",
     tiles_per_step: int = 8,
     cand_bits: int = 32,
-    sched_share: bool = True,
-    width: Optional[Union[int, str]] = None,
-    counters: Optional[Dict[str, int]] = None,
     progress: Optional[ProgressFn] = None,
 ) -> Iterator[Optional[Result]]:
     """The production >2^32 search, batched: candidate sweeps over the
     whole rolled range through ONE ``CandidateSearch``, each dispatch
-    covering ``roll_batch`` roll rows (one batched roll call + one
-    batched sweep call per window — no header bytes ever cross the host
-    boundary, BASELINE.json:9-10). ``roll_batch=1`` is the A/B
-    baseline: the pre-batching per-segment loop, one ``CandidateSearch``
-    and one scalar roll per extranonce segment.
+    covering ``roll_batch`` roll rows of ``tile_width(nonce_bits,
+    slab)`` nonces (one batched roll call + one batched sweep call per
+    window — no header bytes ever cross the host boundary,
+    BASELINE.json:9-10).
 
-    ``sched_share`` (ISSUE 16) turns on the AsicBoost-grade shared-
-    schedule layer: the sweep hashes through the truncated unrolled
-    second compression (:func:`_jnp_candidate_ok_sched`, ~34× per hash
-    measured on CPU) and the batched roll dedupes identical extranonce
-    rows before dispatch (:func:`tpuminter.ops.merkle.roll_batch_deduped`).
-    ``sched_share=False`` is the bit-for-bit A/B baseline — the exact
-    pre-ISSUE-16 programs (house rule since PR 7).
+    The shared-schedule layer (ISSUE 16) is the only body: the sweep
+    hashes through the truncated unrolled second compression
+    (:func:`_jnp_candidate_ok_sched`, or the kernel's ``sym.prepare_hdr``
+    hoist on the Pallas engine) and the batched roll dedupes identical
+    extranonce rows before dispatch
+    (:func:`tpuminter.ops.merkle.roll_batch_deduped`).
 
-    ``width`` overrides the sweep row width: ``None`` keeps the legacy
-    cap-derived ``tile_width(nonce_bits, slab)``; ``"auto"`` caps it at
-    the :func:`autotune_width` probe winner; an int caps it explicitly
-    (all still clamped by ``slab`` and the nonce space).
-
-    ``counters`` (optional dict) accumulates ``rolls``/``sweeps`` —
-    device dispatch evidence for bench.py's rolled A/B fields.
     ``progress`` (:data:`ProgressFn`) receives the settled global-index
     high-water after each resolved window — the roll-budget beacon feed.
     """
     assert req.rolled and req.header is not None and req.target is not None
+    _check_roll_batch(roll_batch)
     engine = _resolve_engine(engine)
-    verify = rolled_verifier(req)
-    hw1_cap = jnp.uint32(int(ops.target_to_words(req.target)[1]))
     from tpuminter.ops import merkle
 
-    if roll_batch <= 1:
-        yield from _mine_rolled_fast_segmented(
-            req, verify, hw1_cap, slab=slab, depth=depth, engine=engine,
-            tiles_per_step=tiles_per_step, cand_bits=cand_bits,
-            counters=counters, progress=progress,
-        )
-        return
-
-    cap = slab
-    if width == "auto":
-        cap = min(slab, autotune_width(
-            cand_bits=cand_bits, sched_share=sched_share, rows=roll_batch))
-    elif width is not None:
-        cap = min(slab, int(width))
-    width = tile_width(req.nonce_bits, cap)
+    hw1_cap = jnp.uint32(int(ops.target_to_words(req.target)[1]))
+    width = tile_width(req.nonce_bits, slab)
     rows = roll_batch + 2
     window = roll_batch * width
     if window >= 1 << 32:
@@ -524,26 +411,17 @@ def mine_rolled_fast(
             plan_tiles(start, n, req.nonce_bits, width, rows, hard_end),
             roll_batch,
         )
-        _count(counters, "rolls")
-        _count(counters, "sweeps")
-        if sched_share:
-            mids, tails = merkle.roll_batch_deduped(
-                roll, plan.en_hi, plan.en_lo)
-        else:
-            mids, tails = roll(
-                jnp.asarray(plan.en_hi), jnp.asarray(plan.en_lo))
+        mids, tails = merkle.roll_batch_deduped(roll, plan.en_hi, plan.en_lo)
         args = (
             mids, tails, jnp.asarray(plan.bases), jnp.asarray(plan.valids),
             jnp.asarray(plan.goffs), hw1_cap,
         )
         if engine == "pallas":
-            return _pallas_batched_candidate_sweep(
-                *args, width, tiles_per_step, sched_share
-            )
-        return _jnp_batched_candidate_sweep(*args, width, cand_bits, sched_share)
+            return _pallas_batched_candidate_sweep(*args, width, tiles_per_step)
+        return _jnp_batched_candidate_sweep(*args, width, cand_bits)
 
     search = CandidateSearch(
-        sweep, resolve_handle, verify, req.lower, req.upper,
+        sweep, resolve_handle, rolled_verifier(req), req.lower, req.upper,
         slab=window, depth=depth, domain=1 << span_bits(req),
     )
     for _ in search.events():
@@ -554,84 +432,6 @@ def mine_rolled_fast(
         req, out.found, out.nonce, out.hash_value, out.searched,
         out.candidates,
     )
-
-
-def _mine_rolled_fast_segmented(
-    req, verify, hw1_cap, *, slab, depth, engine, tiles_per_step,
-    cand_bits, counters, progress=None,
-) -> Iterator[Optional[Result]]:
-    """The pre-batching baseline (``roll_batch=1``): one scalar roll +
-    one drained-to-completion ``CandidateSearch`` per extranonce
-    segment. Kept bit-for-bit reachable so the batched path always has
-    an in-tree A/B."""
-    from tpuminter.ops import merkle
-
-    roll = merkle.make_extranonce_roll(
-        req.header, req.coinbase_prefix, req.coinbase_suffix,
-        req.extranonce_size, req.branch,
-    )
-    # the pallas baseline keeps the full production slab (single-compile
-    # policy); the jnp engine sizes dispatches like the batched rows so
-    # the A/B isolates orchestration, not per-dispatch shape
-    width = tile_width(req.nonce_bits, slab)
-    seg_slab = slab if engine == "pallas" else width
-    searched = 0
-    candidates = []  # (global index, hash)
-    best_hg = None  # (hash, global index) running min over candidates
-    for en, base_g, n_lo, n_hi in chain.rolled_segments(
-        req.lower, req.upper, req.nonce_bits
-    ):
-        mid, tailw = roll(jnp.uint32(en >> 32), jnp.uint32(en & 0xFFFFFFFF))
-        _count(counters, "rolls")
-
-        def sweep(base: int, n: int, _mid=mid, _tailw=tailw):
-            _count(counters, "sweeps")
-            if engine == "pallas":
-                from tpuminter.kernels import pallas_search_candidates_hdr
-
-                found, off = pallas_search_candidates_hdr(
-                    _mid, _tailw, jnp.uint32(base), seg_slab,
-                    tiles_per_step, hw1_cap,
-                )
-                return pack_handle(found, off)
-            return _jnp_segment_candidate_sweep(
-                _mid, _tailw, jnp.uint32(base), hw1_cap, seg_slab, cand_bits
-            )
-
-        def seg_verify(nonce: int, _base_g=base_g) -> Tuple[bool, int]:
-            return verify(_base_g | nonce)
-
-        search = CandidateSearch(
-            sweep, resolve_handle, seg_verify, n_lo, n_hi,
-            slab=seg_slab, depth=depth,
-        )
-        for _ in search.events():
-            if progress is not None and search.outcome is None:
-                local = search.settled_high_water()
-                if local is not None:
-                    hw = base_g | local
-                elif base_g > req.lower:
-                    hw = base_g - 1  # prior segments fully settled
-                else:
-                    hw = None
-                if hw is not None:
-                    seg_best = search.best_candidate()
-                    pool = [b for b in (best_hg, seg_best and (
-                        seg_best[0], base_g | seg_best[1])) if b]
-                    bh, bg = min(pool) if pool else (MIN_UNTRACKED, req.lower)
-                    progress(hw, bg, bh)
-            yield None
-        out = search.outcome
-        searched += out.searched
-        candidates += [(base_g | n, h) for n, h in out.candidates]
-        best_hg = min(((h, g) for g, h in candidates), default=None)
-        if out.found:
-            yield _fast_result(
-                req, True, base_g | out.nonce, out.hash_value, searched,
-                candidates,
-            )
-            return
-    yield _fast_result(req, False, None, None, searched, candidates)
 
 
 # ---------------------------------------------------------------------------
@@ -691,8 +491,6 @@ def mine_rolled_tracking(
     width_cap: int = 1 << 14,
     depth: int = 2,
     roll_batch: int = 8,
-    sched_share: bool = True,
-    counters: Optional[Dict[str, int]] = None,
     progress: Optional[ProgressFn] = None,
 ) -> Iterator[Optional[Result]]:
     """Exact rolled search (CpuMiner-compatible first winner AND
@@ -702,25 +500,24 @@ def mine_rolled_tracking(
     at each one). jnp engine — compiles on every backend, one program
     for every job and extranonce (the dynamic-header property); the
     toy-easy-target correctness path plus JaxMiner's production rolled
-    path. Batched rows ≡ the per-segment loop bit-for-bit
-    (tests/test_extranonce.py pins it).
+    path. Pinned to brute force in tests/test_extranonce.py.
 
-    ``sched_share`` here buys ONLY the roll-side dedup
-    (:func:`tpuminter.ops.merkle.roll_batch_deduped`): the tracking
+    The roll goes through the extranonce dedup
+    (:func:`tpuminter.ops.merkle.roll_batch_deduped`); the tracking
     step itself keeps the scanned full-digest compress. Sharing the
     unrolled schedule inside the full-digest + lexicographic-min fold
     was measured and REJECTED — every fold structure tried either lost
     outright or paid a 15-42 s compile per width (PERF.md §Round 14);
     the truncated e60/e61 trick doesn't apply when all 8 digest words
-    feed the min fold. ``False`` restores the exact pre-ISSUE-16 roll
-    dispatch for A/B.
+    feed the min fold.
     """
     assert req.rolled and req.target is not None
+    _check_roll_batch(roll_batch)
     from tpuminter.ops import merkle
 
     width = tile_width(req.nonce_bits, width_cap)
-    rows = max(roll_batch, 1) + 2
-    window = max(roll_batch, 1) * width
+    rows = roll_batch + 2
+    window = roll_batch * width
     hard_end = (1 << span_bits(req)) - 1
     roll = merkle.make_extranonce_roll_batch(
         req.header, req.coinbase_prefix, req.coinbase_suffix,
@@ -734,16 +531,9 @@ def mine_rolled_tracking(
         n = min(window, req.upper - start + 1)
         plan = lean_plan(
             plan_tiles(start, n, req.nonce_bits, width, rows, hard_end),
-            max(roll_batch, 1),
+            roll_batch,
         )
-        _count(counters, "rolls")
-        _count(counters, "sweeps")
-        if sched_share:
-            mids, tails = merkle.roll_batch_deduped(
-                roll, plan.en_hi, plan.en_lo)
-        else:
-            mids, tails = roll(
-                jnp.asarray(plan.en_hi), jnp.asarray(plan.en_lo))
+        mids, tails = merkle.roll_batch_deduped(roll, plan.en_hi, plan.en_lo)
         return _tracking_step(
             mids, tails, jnp.asarray(plan.bases), jnp.asarray(plan.valids),
             jnp.asarray(plan.goffs), target_words, width,

@@ -25,7 +25,7 @@ This module owns the host half:
 The driver is deliberately generic over three callables (``sweep``,
 ``resolve``, ``verify``) so its queueing/ordering logic is testable on
 CPU with a scripted fake device (tests/test_search.py) and reusable by
-both the single-chip TpuMiner and the bench harness.
+the single-chip TpuMiner, the rolled sweeps and the pod.
 """
 
 from __future__ import annotations
@@ -78,8 +78,8 @@ def resolve_handle(handle) -> Tuple[int, int]:
 
 def timed_call(fn, args) -> float:
     """Wall-clock ONE device call, dispatch through completion — the
-    shared probe primitive behind the one-shot width autotunes
-    (``rolled.autotune_width``, ``ops.splitmix.autotune_lane_width``).
+    probe primitive behind the one-shot width autotune
+    (``ops.splitmix.autotune_lane_width``).
     Blocks via ``block_until_ready`` when the return value offers it;
     callers that sync some other way (``np.asarray`` inside ``fn``)
     just return a plain value."""

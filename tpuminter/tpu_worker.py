@@ -70,8 +70,7 @@ DEFAULT_DEPTH = 2
 
 def make_header_search(header80: bytes, target: int, tiles_per_step: int = 8):
     """The production sweep/resolve/verify triple for a header-mining
-    job, shared by TpuMiner and the bench harness (so the benchmark
-    measures exactly the shipping code path):
+    job:
 
     - ``sweep(base, n)`` dispatches the candidate kernel with the
       target's hash-word-1 cap baked in dynamically (candidates are
@@ -113,7 +112,6 @@ class TpuMiner(Miner):
         depth: int = DEFAULT_DEPTH,
         exact_min: bool = False,
         roll_batch: int = 8,
-        sched_share: bool = True,
     ):
         if jax.default_backend() == "cpu":
             raise RuntimeError(
@@ -125,13 +123,8 @@ class TpuMiner(Miner):
         self.exact_min = exact_min
         #: extranonce rows per rolled dispatch (tpuminter.rolled): the
         #: batched roll + batched dynamic-header kernel sweep many
-        #: segments per launch; 1 = the per-segment A/B baseline
+        #: segments per launch
         self.roll_batch = roll_batch
-        #: ISSUE 16 schedule-sharing layer on the rolled path: the
-        #: shared-schedule kernel body (sym.prepare_hdr hoist) for the
-        #: fast sweep + the extranonce-roll dedup on both rolled paths.
-        #: False restores the exact pre-ISSUE-16 programs for A/B.
-        self.sched_share = sched_share
         self._scrypt_delegate = None
         # scheduler hint: ask for chunks a few slabs deep
         self.lanes = lanes if lanes is not None else (slab * 4) // 16_384
@@ -186,11 +179,6 @@ class TpuMiner(Miner):
 
     # -- TARGET + extranonce rolling (BASELINE.json:9-10) -----------------
 
-    def _rolled_segments(self, req: Request):
-        """Global-index range → per-extranonce segments
-        ``(en, global_base, n_lo, n_hi)`` (``chain.rolled_segments``)."""
-        return chain.rolled_segments(req.lower, req.upper, req.nonce_bits)
-
     def _mine_rolled_fast(self, req: Request) -> Iterator[Optional[Result]]:
         """The production >2^32 search: the roll (coinbase txid →
         branch fold → merkle root → header midstate) runs ON DEVICE and
@@ -200,75 +188,27 @@ class TpuMiner(Miner):
         roll + one kernel launch cover ``roll_batch`` segments' worth of
         global indices, and ONE pipelined ``CandidateSearch`` spans the
         whole rolled range — the depth-2 buffering no longer dies at
-        segment boundaries. ``roll_batch=1`` reproduces the per-segment
-        loop (the A/B baseline)."""
+        segment boundaries."""
         from tpuminter import rolled
 
         yield from rolled.mine_rolled_fast(
             req, slab=self.slab, depth=self.depth,
             roll_batch=self.roll_batch, engine="pallas",
-            sched_share=self.sched_share, progress=self.progress_cb,
+            progress=self.progress_cb,
         )
 
     def _mine_rolled_tracking(self, req: Request) -> Iterator[Optional[Result]]:
         """Rolled search at toy-easy targets (≥ 2^224, where the
         candidate test is not a necessary condition): exact tracking,
-        CpuMiner-compatible. Default: the batched dynamic-header sweep
+        CpuMiner-compatible, through the batched dynamic-header sweep
         (``rolled.mine_rolled_tracking`` — one compile for every
-        extranonce AND every job, where the per-segment loop below
-        recompiles ``pallas_search_target`` per rolled header, ~20-40 s
-        each). ``roll_batch=1`` keeps that loop as
-        the baseline. Correctness path only — real difficulties take
-        :meth:`_mine_rolled_fast`."""
-        assert req.target is not None
-        if self.roll_batch > 1:
-            from tpuminter import rolled
+        extranonce AND every job). Correctness path only — real
+        difficulties take :meth:`_mine_rolled_fast`."""
+        from tpuminter import rolled
 
-            yield from rolled.mine_rolled_tracking(
-                req, width_cap=min(self.slab, 1 << 16), depth=self.depth,
-                roll_batch=self.roll_batch, sched_share=self.sched_share,
-                progress=self.progress_cb,
-            )
-            return
-        cb = chain.CoinbaseTemplate(
-            req.coinbase_prefix, req.coinbase_suffix, req.extranonce_size
-        )
-        best: Optional[Tuple[int, int]] = None  # (hash, global index)
-        searched = 0
-        for en, base_g, n_lo, n_hi in self._rolled_segments(req):
-            hdr = chain.rolled_header(req.header, cb, req.branch, en)
-            sub = Request(
-                job_id=req.job_id, mode=PowMode.TARGET, lower=n_lo,
-                upper=n_hi, header=hdr.pack(), target=req.target,
-                chunk_id=req.chunk_id,
-            )
-            seg_result: Optional[Result] = None
-            for item in self._mine_target_tracking(sub):
-                if item is None:
-                    yield None
-                else:
-                    seg_result = item
-            assert seg_result is not None
-            g = base_g | seg_result.nonce
-            if seg_result.found:
-                yield Result(
-                    req.job_id, req.mode, g, seg_result.hash_value,
-                    found=True, searched=searched + seg_result.searched,
-                    chunk_id=req.chunk_id,
-                )
-                return
-            searched += seg_result.searched
-            cand = (seg_result.hash_value, g)
-            if best is None or cand < best:
-                best = cand
-            if self.progress_cb is not None and (base_g | n_hi) < req.upper:
-                # segment-boundary granularity is enough for the
-                # roll_batch=1 baseline arm
-                self.progress_cb(base_g | n_hi, best[1], best[0])
-        yield Result(
-            req.job_id, req.mode, best[1], best[0],
-            found=best[0] <= req.target,
-            searched=searched, chunk_id=req.chunk_id,
+        yield from rolled.mine_rolled_tracking(
+            req, width_cap=min(self.slab, 1 << 16), depth=self.depth,
+            roll_batch=self.roll_batch, progress=self.progress_cb,
         )
 
     # -- TARGET: exact-min tracking kernel (compat path) ------------------

@@ -763,9 +763,8 @@ def _build_miner(
 
     ``exact_min``/``slab``/``depth``/``roll_batch`` tune the device
     backends (ADVICE.md r2: fleets needing CpuMiner-compatible
-    exhausted-range minima opt in via ``--exact-min``; ``--roll-batch
-    1`` pins the per-segment rolled baseline); the other backends
-    ignore them.
+    exhausted-range minima opt in via ``--exact-min``); the other
+    backends ignore them.
     """
     if backend == "cpu":
         return CpuMiner()
@@ -846,9 +845,8 @@ def main(argv: Optional[list] = None) -> None:
         "--roll-batch", type=int, default=None,
         help="jax/tpu/pod backends: extranonce rows per rolled dispatch "
         "(default 8) — one batched roll + one batched sweep cover that "
-        "many segments' worth of indices per device call; 1 reproduces "
-        "the per-segment loop (the A/B baseline, README 'Rolled "
-        "sweeps')",
+        "many segments' worth of indices per device call (README "
+        "'Rolled sweeps')",
     )
     parser.add_argument(
         "--profile", metavar="DIR", default=None,
