@@ -7,6 +7,7 @@ the pipelining/ordering/remainder logic is pinned without a TPU
 """
 
 import random
+from collections import Counter
 
 import pytest
 
@@ -18,21 +19,30 @@ class FakeChip:
 
     ``candidates``: sorted nonces whose digest word 7 "is zero".
     ``winners``: subset that also beats the target.
+    ``chained``: honour the ``after`` handle as the kernel's ``stop``
+    operand does — no work when it reports found or skipped; otherwise
+    ignore it, as the sweeps that do not chain do.
     """
 
-    def __init__(self, candidates, winners):
+    def __init__(self, candidates, winners, chained=False):
         self.candidates = sorted(candidates)
         self.winners = set(winners)
         assert self.winners <= set(self.candidates)
+        self.chained = chained
         self.sweeps = []  # (base, n) log, dispatch order
+        self.skipped = []  # (base, n) of the sweeps that did no work
         self.verifies = []
 
-    def sweep(self, base, n):
+    def sweep(self, base, n, after):
         self.sweeps.append((base, n))
+        assert len(self.sweeps) < 10_000, "the search does not converge"
+        if self.chained and after is not None and (after[0] or after[2]):
+            self.skipped.append((base, n))
+            return (0, 0, 1)
         hit = next(
             (c for c in self.candidates if base <= c < base + n), None
         )
-        return (0, 0) if hit is None else (1, hit - base)
+        return (0, 0, 0) if hit is None else (1, hit - base, 0)
 
     def resolve(self, handle):
         return handle
@@ -111,9 +121,9 @@ def test_exhausted_best_is_min_candidate():
 
 def test_pad_lane_hit_past_range_is_clean_cover():
     class PadChip(FakeChip):
-        def sweep(self, base, n):
+        def sweep(self, base, n, after):
             self.sweeps.append((base, n))
-            return (1, n + 7)  # fired past the real range
+            return (1, n + 7, 0)  # fired past the real range
 
     chip = PadChip([], [])
     out = chip.search(0, 999)
@@ -121,19 +131,20 @@ def test_pad_lane_hit_past_range_is_clean_cover():
     assert chip.verifies == []
 
 
-@pytest.mark.parametrize("seed", range(20))
-def test_randomized_matches_bruteforce(seed):
+@pytest.mark.parametrize("seed, chained", [
+    *(pytest.param(s, False, id=str(s)) for s in range(20)),
+    *(pytest.param(s, True, id=f"{s}-chained") for s in range(20)),
+])
+def test_randomized_matches_bruteforce(seed, chained):
     rng = random.Random(seed)
     lower, upper = 0, rng.randrange(200, 2000)
     space = range(lower, upper + 1)
     candidates = sorted(rng.sample(space, rng.randrange(0, 12)))
     winners = [c for c in candidates if rng.random() < 0.4]
-    chip = FakeChip(candidates, winners)
-    out = chip.search(
-        lower, upper,
-        slab=rng.choice([37, 100, 256, 4096]),
-        depth=rng.choice([1, 2, 3]),
-    )
+    chip = FakeChip(candidates, winners, chained=chained)
+    slab = rng.choice([37, 100, 256, 4096])
+    depth = rng.choice([1, 2, 3])
+    out = chip.search(lower, upper, slab=slab, depth=depth)
     if winners:
         assert out.found and out.nonce == min(winners)
     else:
@@ -141,6 +152,41 @@ def test_randomized_matches_bruteforce(seed):
         assert out.searched == upper - lower + 1
         if candidates:
             assert out.best == (1 << 230, min(candidates))
+    # no cascade: each candidate skips at most the sweeps behind it
+    assert len(chip.skipped) <= depth * len(candidates)
+
+
+def test_chained_win_skips_the_sweep_behind_it():
+    chip = FakeChip([350], [350], chained=True)
+    out = chip.search(0, 999, slab=100, depth=2)
+    assert out.found and (out.nonce, out.hash_value) == (350, 1 << 200)
+    # brute force sweeps [0, 350] and stops there
+    assert out.searched == 351
+    # the slab dispatched behind the winner's skipped on the device, and
+    # nothing above it was issued
+    assert chip.skipped == [(400, 100)]
+    assert [b for b, _ in chip.sweeps] == [0, 100, 200, 300, 400]
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_chained_false_positive_reissues_the_skipped_range(depth):
+    # 150 is a false positive; the sweeps behind it skip, their ranges
+    # go back to the queue and the search still finds the lowest winner
+    chip = FakeChip([150, 420, 610], [420, 610], chained=True)
+    out = chip.search(0, 999, slab=100, depth=depth)
+    assert out.found and out.nonce == 420
+    assert out.candidates[0] == (150, 1 << 230)
+    # every nonce below the winner was swept by a sweep that did work
+    worked = Counter(chip.sweeps) - Counter(chip.skipped)
+    covered = set()
+    for b, n in worked:
+        covered.update(range(b, b + n))
+    assert set(range(421)) <= covered
+    # at most depth - 1 sweeps were in flight behind each candidate
+    assert len(chip.skipped) <= 2 * (depth - 1)
+    if depth > 1:
+        assert (200, 100) in chip.skipped
+        assert [b for b, _ in chip.sweeps].count(200) == 2  # re-issued
 
 
 # -- pipeline_spans: the generic double-buffer (MIN/scrypt/exact-min) ----

@@ -97,8 +97,8 @@ def test_candidate_search_leaves_one_sweep_unresolved_behind_its_winner(tmp_path
     in flight, and that one is never resolved."""
     slab = 1 << 10
 
-    def sweep(base, n):
-        return (1, 5) if base == 0 else (0, 0)
+    def sweep(base, n, after):
+        return (1, 5, 0) if base == 0 else (0, 0, 0)
 
     def verify(nonce):
         return True, nonce

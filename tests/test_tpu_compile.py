@@ -30,30 +30,8 @@ SLAB = 1 << 27
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.experimental import topologies
-
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("TPU_LOG_DIR", "disabled")
-        try:
-            topo = topologies.get_topology_desc(
-                platform="tpu", topology_name="v5e:2x2"
-            )
-        except Exception as e:
-            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a compile for a described chip is written to the persistent cache
-    # but cannot be read back without the chip; keep it out, and keep
-    # these non-interpret traces out of the in-memory caches other
-    # tests in this worker read
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    jax.clear_caches()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.clear_caches()
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.fixture
@@ -82,6 +60,19 @@ def test_search_candidates_compiles_at_production_slab(compiled_for):
             tmpl, base, SLAB, 8, cap
         ),
         ((), U32), ((), U32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_chained_search_candidates_compiles_at_production_slab(compiled_for):
+    """TpuMiner's TARGET sweep: chained on the sweep before it
+    (``stop`` a packed handle)."""
+    tmpl = ops.header_template(chain.GENESIS_HEADER.pack())
+    text = compiled_for(
+        lambda base, cap, stop: ksha.pallas_search_candidates(
+            tmpl, base, SLAB, 8, cap, stop
+        ),
+        ((), U32), ((), U32), ((3,), U32),
     )
     assert "tpu_custom_call" in text
 

@@ -2,10 +2,13 @@
 NameError in ``_mine_rolled_fast``'s search wiring hid behind the TPU
 gate because the Pallas kernels only compile on a real chip).
 
-The KERNELS stay TPU-only (tests/test_kernels_tpu.py pins them on
-hardware); here they are monkeypatched with CPU fakes so the DRIVERS —
-segment iteration, CandidateSearch wiring, pack/resolve handles,
-result assembly — execute on every CI run.
+The kernels' speed and full-size results are pinned on hardware
+(tests/test_kernels_tpu.py); here they are monkeypatched with CPU fakes
+so the DRIVERS — segment iteration, CandidateSearch wiring, pack/resolve
+handles, result assembly — execute on every CI run. The chained
+candidate sweep's skip runs the kernel itself, in interpret mode at the
+smallest size, and the pod program it must leave alone is lowered for a
+described chip.
 """
 
 import jax.numpy as jnp
@@ -37,8 +40,9 @@ def _drain(gen):
 
 
 def _clean_kernel(*_args, **_kw):
-    """A kernel fake reporting 'no candidate anywhere' (found=0)."""
-    return jnp.uint32(0), jnp.uint32(0x7FFFFFFF)
+    """A chained-kernel fake reporting 'no candidate anywhere' (found=0,
+    skipped=0)."""
+    return jnp.uint32(0), jnp.uint32(0x7FFFFFFF), jnp.uint32(0)
 
 
 def test_target_fast_driver_runs_on_cpu(monkeypatch):
@@ -98,11 +102,13 @@ def test_target_fast_driver_finds_scripted_candidate(monkeypatch):
         chain.dsha256(header[:76] + struct.pack("<I", win))
     )
 
-    def planted_kernel(template, base, n, tiles, cap):
+    def planted_kernel(template, base, n, tiles, cap, stop):
         b = int(base)
+        if int(stop[0]) or int(stop[2]):
+            return jnp.uint32(0), jnp.uint32(0), jnp.uint32(1)
         if b <= win < b + int(n):
-            return jnp.uint32(1), jnp.uint32(win - b)
-        return jnp.uint32(0), jnp.uint32(0x7FFFFFFF)
+            return jnp.uint32(1), jnp.uint32(win - b), jnp.uint32(0)
+        return jnp.uint32(0), jnp.uint32(0x7FFFFFFF), jnp.uint32(0)
 
     monkeypatch.setattr(
         tpu_worker, "pallas_search_candidates", planted_kernel
@@ -116,3 +122,98 @@ def test_target_fast_driver_finds_scripted_candidate(monkeypatch):
     assert result.found
     assert (result.nonce, result.hash_value) == (win, h_win)
     assert result.searched == win + 1
+
+
+# -- the chained candidate sweep, the kernel itself in interpret mode ---------
+
+#: one loop step of one tile: the smallest sweep the kernel takes
+N = 4096
+#: 100 nonces below the genesis nonce, whose hash has a zero top word
+GEN_BASE = chain.GENESIS_HEADER.nonce - 100
+
+
+def _cpu_compiled(fn, *args):
+    """``fn`` compiled for the CPU with XLA's fusion pass off: the
+    interpret-mode SHA kernel then compiles in seconds (minutes with it
+    on, ``rolled._jnp_batched_sweep_unfused``)."""
+    import jax
+
+    return jax.jit(fn).lower(*args).compile(
+        compiler_options={"xla_disable_hlo_passes": "fusion"}
+    )
+
+
+@pytest.fixture(scope="module")
+def cand_sweeps():
+    """(unchained, chained) interpret-mode candidate sweeps of N nonces
+    of the genesis header."""
+    from tpuminter.kernels import pallas_search_candidates
+    from tpuminter.ops import sha256 as ops
+
+    tmpl = ops.header_template(chain.GENESIS_HEADER.pack())
+    base, stop = jnp.uint32(0), jnp.zeros(3, jnp.uint32)
+    plain = _cpu_compiled(
+        lambda b: pallas_search_candidates(tmpl, b, N, 1), base
+    )
+    chained = _cpu_compiled(
+        lambda b, s: pallas_search_candidates(tmpl, b, N, 1, None, s),
+        base, stop,
+    )
+    return plain, chained
+
+
+@pytest.mark.parametrize("base", [GEN_BASE, chain.GENESIS_HEADER.nonce + 1])
+def test_chained_sweep_behind_go_equals_the_unchained_sweep(cand_sweeps, base):
+    plain, chained = cand_sweeps
+    found, off = (int(x) for x in plain(jnp.uint32(base)))
+    if base == GEN_BASE:
+        assert (found, off) == (1, 100)
+    go = tpu_worker._go_handle()
+    assert [int(x) for x in chained(jnp.uint32(base), go)] == [found, off, 0]
+
+
+@pytest.mark.parametrize("stop", [(1, 100, 0), (0, 0, 1), (1, 0, 1)])
+def test_chained_sweep_skips_behind_found_or_skipped(cand_sweeps, stop):
+    _, chained = cand_sweeps
+    out = chained(jnp.uint32(GEN_BASE), jnp.asarray(stop, jnp.uint32))
+    assert [int(x) for x in out] == [0, 0, 1]
+
+
+#: sha256 of the StableHLO of ``jit_pod_candidate_sweep`` (genesis header,
+#: slab 2^27 a chip, 4 stripes, a described v5e 2x2), lowered with no
+#: source tracebacks in its locations, from the tree before
+#: ``pallas_search_candidates`` took ``stop``: the pod program must stay
+#: that one
+POD_SWEEP_DIGEST = (
+    "44a13e22689641c0838095571936d97e9fba7bde8996ec2e50bb8d03cf5be32b"
+)
+
+
+def test_pod_sweep_program_is_unchanged_by_the_chain(v5e_2x2, monkeypatch):
+    import hashlib
+
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    import tpuminter.kernels.sha256 as ksha
+    from tpuminter.ops import sha256 as ops
+    from tpuminter.parallel.mesh import build_candidate_sweep, make_mesh
+
+    monkeypatch.setattr(ksha, "_interpret", lambda: False)
+    mesh = make_mesh(v5e_2x2.devices)
+    sweep = build_candidate_sweep(
+        mesh, ops.header_template(chain.GENESIS_HEADER.pack()),
+        slab_per_device=1 << 27, n_slabs=4, kernel="pallas",
+    )
+    rep = NamedSharding(mesh, PartitionSpec())
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        text = sweep.lower(
+            jax.ShapeDtypeStruct((), jnp.uint32, sharding=rep),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
+        ).as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+    assert "module @jit_pod_candidate_sweep " in text
+    assert hashlib.sha256(text.encode()).hexdigest() == POD_SWEEP_DIGEST

@@ -373,6 +373,7 @@ def pallas_search_candidates(
     n: int,
     tiles_per_step: int = 8,
     hw1_cap: jnp.ndarray | None = None,
+    stop: jnp.ndarray | None = None,
 ):
     """Fast sweep of ``n`` consecutive nonces from scalar ``base`` for
     *candidates*: nonces whose double-SHA-256 hash value has top word
@@ -390,11 +391,30 @@ def pallas_search_candidates(
     host-side verification + remainder re-issue —
     ``tpuminter.search.CandidateSearch``). The hot loop carries no
     byteswap/256-bit-compare/min-fold baggage — full evaluation happens
-    host-side for the rare survivors."""
+    host-side for the rare survivors.
+
+    ``stop`` chains the sweep behind the one dispatched just before it:
+    that sweep's packed handle (``search.pack_handle``: found,
+    first_off, skipped). When the handle reports found or skipped, this
+    sweep does no nonce work and returns ``(0, 0, 1)``; otherwise
+    ``(found, first_off, 0)``. The test is a ``lax.cond`` around the
+    kernel on the device, so a slab queued behind a candidate costs no
+    host round trip and the kernel itself is the same. Without ``stop``
+    the program is the unchained one, returning ``(found, first_off)``."""
     if not 1 <= n <= 1 << 30:
         raise ValueError("n must be in [1, 2^30] (int32 offset domain)")
     if hw1_cap is None:
         hw1_cap = jnp.uint32(0xFFFFFFFF)
+    if stop is not None:
+        # the working branch is the unchained program, nested: its
+        # kernel op keeps the name a device trace shows for it
+        zero = jnp.uint32(0)
+        return jax.lax.cond(
+            (stop[0] != 0) | (stop[2] != 0),
+            lambda: (zero, zero, jnp.uint32(1)),
+            lambda: (*pallas_search_candidates(
+                template, base, n, tiles_per_step, hw1_cap), zero),
+        )
     chunk = _TILE[0] * LANES * tiles_per_step
     n_tiles = -(-n // chunk) * tiles_per_step
     cap_biased = jax.lax.bitcast_convert_type(

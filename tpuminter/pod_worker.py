@@ -306,8 +306,9 @@ class PodMiner(Miner):
         sweep reporting the LOWEST candidate offset — which the stripe
         design guarantees pod-wide (``parallel.build_candidate_sweep``)."""
 
-        def sweep(base: int, n: int):
-            # the stripes a call took are not pulled to the host: a
+        def sweep(base: int, n: int, after):
+            # the pod program does not chain: ``after`` is not read.
+            # The stripes a call took are not pulled to the host: a
             # device trace shows them, one kernel op a stripe per chip
             found, off, _ = sweep_fn(jnp.uint32(base))
             return pack_handle(found, off)
@@ -387,7 +388,8 @@ class PodMiner(Miner):
         hard_end = (1 << rolled.span_bits(req)) - 1
         n_dev = self.n_dev
 
-        def sweep(start: int, n: int):
+        def sweep(start: int, n: int, after):
+            # does not chain: ``after`` is not read
             plan = rolled.plan_tiles(
                 start, n, req.nonce_bits, width, rows, hard_end,
                 interleave=n_dev,

@@ -406,7 +406,8 @@ def mine_rolled_fast(
         req.extranonce_size, req.branch,
     )
 
-    def sweep(start: int, n: int):
+    def sweep(start: int, n: int, after):
+        # the batched kernel does not chain: ``after`` is not read
         plan = lean_plan(
             plan_tiles(start, n, req.nonce_bits, width, rows, hard_end),
             roll_batch,

@@ -21,6 +21,14 @@ This module owns the host half:
   nonce below W has been searched, so the reported winner is exactly
   the lowest winning nonce in the range — the same contract as the
   sequential CPU miner (SURVEY.md §3.2's loop semantics).
+- **Chained skip.** Each dispatch is handed the handle of the sweep in
+  flight just before it. A sweep that chains on it (the single-chip
+  kernel's ``stop`` operand) does no work when that handle reports a
+  candidate or a skip, so the slab queued behind a winner costs the
+  device microseconds, not a slab. A skipped range goes back to the
+  work queue; after a candidate resolves, no new dispatch chains on a
+  handle issued before it, so a false positive skips at most ``depth``
+  sweeps and never cascades.
 
 The driver is deliberately generic over three callables (``sweep``,
 ``resolve``, ``verify``) so its queueing/ordering logic is testable on
@@ -40,23 +48,29 @@ __all__ = [
     "CandidateSearch", "SearchOutcome", "pipeline_spans", "pull", "timed_call",
 ]
 
-#: sweep(base, n) -> opaque handle (asynchronous dispatch)
-SweepFn = Callable[[int, int], object]
-#: resolve(handle) -> (found, first_off); blocks until the call is done
-ResolveFn = Callable[[object], Tuple[int, int]]
+#: sweep(base, n, after) -> opaque handle (asynchronous dispatch);
+#: ``after`` is the handle of the sweep in flight just before, or None
+SweepFn = Callable[[int, int, Optional[object]], object]
+#: resolve(handle) -> (found, first_off, skipped); blocks until the call
+#: is done. ``skipped`` is nonzero only for a sweep that chained on
+#: ``after`` and did no work.
+ResolveFn = Callable[[object], Tuple[int, int, int]]
 #: verify(nonce) -> (wins, hash_value) — full host-side evaluation
 VerifyFn = Callable[[int], Tuple[bool, int]]
 
 
-def pack_handle(found, off):
-    """Pack a sweep's (found, first_off) device scalars into ONE device
-    array — the canonical CandidateSearch handle. Resolving two scalars
-    separately costs two host round-trips per slab (the measured
-    0.98 → 1.005 GH/s difference). Layout: index 0 = found, 1 = first_off — keep in sync
-    with :func:`resolve_handle`, the only reader."""
+def pack_handle(found, off, skipped=None):
+    """Pack a sweep's (found, first_off[, skipped]) device scalars into
+    ONE device array — the canonical CandidateSearch handle. Resolving
+    the scalars separately costs a host round-trip each per slab (the
+    measured 0.98 → 1.005 GH/s difference). Layout: index 0 = found,
+    1 = first_off, 2 = skipped, present only for a sweep that chains
+    (``kernels.pallas_search_candidates``' ``stop``) — keep in sync with
+    :func:`resolve_handle`, the only host reader, and with that
+    kernel's ``stop`` test, the device reader."""
     import jax.numpy as jnp
 
-    return jnp.stack([found, off])
+    return jnp.stack([found, off] if skipped is None else [found, off, skipped])
 
 
 def pull(handle):
@@ -68,12 +82,13 @@ def pull(handle):
         return np.asarray(handle)
 
 
-def resolve_handle(handle) -> Tuple[int, int]:
-    """Blocking single-pull resolve of a :func:`pack_handle` handle."""
+def resolve_handle(handle) -> Tuple[int, int, int]:
+    """Blocking single-pull resolve of a :func:`pack_handle` handle:
+    ``(found, first_off, skipped)``, skipped 0 for a handle without it."""
     import numpy as np
 
     arr = np.asarray(handle)
-    return int(arr[0]), int(arr[1])
+    return int(arr[0]), int(arr[1]), int(arr[2]) if arr.size > 2 else 0
 
 
 def timed_call(fn, args) -> float:
@@ -166,7 +181,8 @@ class CandidateSearch:
 
     Contract note (ADVICE.md r2): when a verified win ends the search,
     up to ``depth - 1`` in-flight sweep handles above the winner are
-    simply **abandoned, never resolved**. That is free for JAX async
+    simply **abandoned, never resolved** (a chaining sweep has skipped
+    them on the device). That is free for JAX async
     arrays (the device work is already dispatched and the result is
     garbage-collected), but a ``resolve`` callable that owns real
     resources per handle must tolerate dropped handles — clean them up
@@ -196,11 +212,13 @@ class CandidateSearch:
         self._sweep, self._resolve, self._verify = sweep, resolve, verify
         self.lower, self.upper = lower, upper
         self.slab, self.depth = slab, depth
-        # disjoint unsearched ranges; ascending except re-queued
-        # remainders, which go to the FRONT (they are always lower than
-        # anything else still queued — see _on_candidate)
+        # disjoint unsearched ranges, ascending: a re-queued early-exit
+        # remainder goes to the FRONT (it is lower than anything else
+        # still queued), a skipped range to its place in nonce order
         self._pending: deque = deque([(lower, upper)])
-        self._inflight: deque = deque()  # (start, end, handle) FIFO
+        # (start, end, handle, chainable) FIFO; a sweep chains only on a
+        # chainable handle: one issued after the last resolved candidate
+        self._inflight: deque = deque()
         self._wins: List[Tuple[int, int]] = []  # (nonce, hash)
         self.outcome: Optional[SearchOutcome] = None
         self._searched = 0
@@ -226,13 +244,32 @@ class CandidateSearch:
         # size mid-run costs ~20 s of compile. Sound
         # because the kernel reports the LOWEST candidate offset: a hit
         # past ``end`` (or past 2^32 wrap) proves [start, end] clean.
+        after = None
+        if self._inflight and self._inflight[-1][3]:
+            after = self._inflight[-1][2]
         with span(DISPATCH):
-            handle = self._sweep(start, self.slab)
-        self._inflight.append((start, start + take - 1, handle))
+            handle = self._sweep(start, self.slab, after)
+        self._inflight.append((start, start + take - 1, handle, True))
+
+    def _unchain_inflight(self) -> None:
+        """A candidate resolved: every sweep still in flight may skip on
+        it, directly or through a skipped one before it, so no new
+        dispatch may chain on them."""
+        self._inflight = deque(
+            (s, e, h, False) for s, e, h, _ in self._inflight
+        )
+
+    def _requeue(self, start: int, end: int) -> None:
+        """Put a skipped range back into ``_pending`` in nonce order."""
+        at = next(
+            (i for i, (s, _) in enumerate(self._pending) if s > start),
+            len(self._pending),
+        )
+        self._pending.insert(at, (start, end))
 
     def _unsearched_min(self) -> Optional[int]:
         starts = [s for s, _ in self._pending]
-        starts += [s for s, _, _ in self._inflight]
+        starts += [s for s, _, _, _ in self._inflight]
         return min(starts) if starts else None
 
     def settled_high_water(self) -> Optional[int]:
@@ -293,11 +330,18 @@ class CandidateSearch:
             if not self._inflight:
                 assert self._try_finish(), "no work left but not finished"
                 return
-            start, end, handle = self._inflight.popleft()
+            start, end, handle, _ = self._inflight.popleft()
             with span(RESOLVE):
-                found, off = self._resolve(handle)
+                found, off, skipped = self._resolve(handle)
             n = end - start + 1
-            if not found or off >= n:
+            if found:
+                self._unchain_inflight()
+            if skipped:
+                # chained behind a candidate: nothing swept
+                self._requeue(start, end)
+                if self._wins:
+                    self._prune_pending_above(min(self._wins)[0])
+            elif not found or off >= n:
                 # clean sweep: no candidate at any offset within the
                 # logical range (a hit past it — oversweep slack or a pad
                 # lane — still proves every lower offset candidate-free)

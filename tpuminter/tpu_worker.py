@@ -33,6 +33,7 @@ worker CLI exposes it as ``--backend tpu``.
 
 from __future__ import annotations
 
+import functools
 import struct
 from typing import Iterator, Optional, Tuple
 
@@ -68,14 +69,24 @@ DEFAULT_SLAB = 1 << 27
 DEFAULT_DEPTH = 2
 
 
+@functools.lru_cache(maxsize=None)
+def _go_handle():
+    """The handle a search's first sweep chains on: found 0, skipped 0.
+    One device array for the process, built on first use, so the chained
+    kernel keeps one compiled program per header."""
+    return jnp.zeros(3, jnp.uint32)
+
+
 def make_header_search(header80: bytes, target: int, tiles_per_step: int = 8):
     """The production sweep/resolve/verify triple for a header-mining
     job:
 
-    - ``sweep(base, n)`` dispatches the candidate kernel with the
+    - ``sweep(base, n, after)`` dispatches the candidate kernel with the
       target's hash-word-1 cap baked in dynamically (candidates are
       true wins up to a ~2^-64 tail, so early exits are never wasted),
-    - ``resolve(handle)`` syncs a call's (found, first_off),
+      chained on ``after`` (the go handle when None): it skips on the
+      device when the sweep before it found a candidate or skipped,
+    - ``resolve(handle)`` syncs a call's (found, first_off, skipped),
     - ``verify(nonce)`` re-hashes host-side and applies the exact
       256-bit target compare.
     """
@@ -83,11 +94,12 @@ def make_header_search(header80: bytes, target: int, tiles_per_step: int = 8):
     header76 = header80[:76]
     hw1_cap = jnp.uint32(int(ops.target_to_words(target)[1]))
 
-    def sweep(base: int, n: int):
-        found, off = pallas_search_candidates(
-            template, jnp.uint32(base), n, tiles_per_step, hw1_cap
+    def sweep(base: int, n: int, after):
+        found, off, skipped = pallas_search_candidates(
+            template, jnp.uint32(base), n, tiles_per_step, hw1_cap,
+            _go_handle() if after is None else after,
         )
-        return pack_handle(found, off)
+        return pack_handle(found, off, skipped)
 
     resolve = resolve_handle
 
