@@ -89,13 +89,13 @@ def test_rolled_header_matches_manual_merkle():
 def _roll_one(hdr80, prefix, suffix, en_size, branch):
     """``roll(en) -> (midstate, tail_words)``: row 0 of the batched
     device roll of the single extranonce ``en``."""
-    batch = merkle.make_extranonce_roll_batch(
-        hdr80, prefix, suffix, en_size, branch)
+    template = merkle.roll_template(hdr80, prefix, suffix, en_size, branch)
 
     def roll(en):
-        mids, tails = batch(
-            jnp.asarray([en >> 32], jnp.uint32),
-            jnp.asarray([en & 0xFFFFFFFF], jnp.uint32),
+        mids, tails = merkle.roll_batch_deduped(
+            *template,
+            np.array([en >> 32], np.uint32),
+            np.array([en & 0xFFFFFFFF], np.uint32),
         )
         return mids[0], tails[0]
 
@@ -411,13 +411,11 @@ def test_batched_roll_property_pin():
         branch = tuple(rng.bytes(32) for _ in range(depth))
         cb = chain.CoinbaseTemplate(prefix, suffix, en_size)
         ens = [rnd.randrange(0, 1 << (8 * en_size)) for _ in range(b)]
-        batch = merkle.make_extranonce_roll_batch(
-            hdr80, prefix, suffix, en_size, branch
-        )
         alone = _roll_one(hdr80, prefix, suffix, en_size, branch)
-        mids, tails = batch(
-            jnp.asarray(np.array([e >> 32 for e in ens], np.uint32)),
-            jnp.asarray(np.array([e & 0xFFFFFFFF for e in ens], np.uint32)),
+        mids, tails = merkle.roll_batch_deduped(
+            *merkle.roll_template(hdr80, prefix, suffix, en_size, branch),
+            np.array([e >> 32 for e in ens], np.uint32),
+            np.array([e & 0xFFFFFFFF for e in ens], np.uint32),
         )
         mids, tails = np.asarray(mids), np.asarray(tails)
         for i, en in enumerate(ens):

@@ -194,10 +194,9 @@ from tpuminter.tpu_worker import TpuMiner
 rng2 = np.random.RandomState(0)
 cb_prefix = rng2.bytes(41); cb_suffix = rng2.bytes(60)
 cb_branch = tuple(rng2.bytes(32) for _ in range(2))
-roll_b = merkle.make_extranonce_roll_batch(
-    GEN.pack(), cb_prefix, cb_suffix, 4, cb_branch)
-mids, tails = roll_b(jnp.zeros(3, jnp.uint32),
-                     jnp.asarray(np.array([0, 1, 2], np.uint32)))
+mids, tails = merkle.roll_batch_deduped(
+    *merkle.roll_template(GEN.pack(), cb_prefix, cb_suffix, 4, cb_branch),
+    np.zeros(3, np.uint32), np.array([0, 1, 2], np.uint32))
 W = 1 << 14
 bases = np.array([100, 2804947108 - 5000, 100], np.uint32)  # row 1 wins
 valids = np.array([W, W, 3000], np.uint32)
@@ -243,10 +242,9 @@ from tpuminter.ops import merkle
 rng3 = np.random.RandomState(0)
 cb_prefix = rng3.bytes(41); cb_suffix = rng3.bytes(60)
 cb_branch = tuple(rng3.bytes(32) for _ in range(2))
-roll_b = merkle.make_extranonce_roll_batch(
-    GEN.pack(), cb_prefix, cb_suffix, 4, cb_branch)
-mids, tails = roll_b(jnp.zeros(3, jnp.uint32),
-                     jnp.asarray(np.array([0, 1, 2], np.uint32)))
+mids, tails = merkle.roll_batch_deduped(
+    *merkle.roll_template(GEN.pack(), cb_prefix, cb_suffix, 4, cb_branch),
+    np.zeros(3, np.uint32), np.array([0, 1, 2], np.uint32))
 W = 1 << 14
 bases = np.array([100, 2804947108 - 5000, 100], np.uint32)  # row 1 wins
 valids = np.array([W, W, 0], np.uint32)  # row 2: pure padding

@@ -1178,8 +1178,9 @@ def test_batched_sweep_matches_hashlib_scan(seed, b, width, cand_bits):
     prefix, suffix = rng.bytes(41), rng.bytes(60)
     hdr80 = chain.GENESIS_HEADER.pack()
     ens = rng.randint(0, 1 << 32, b, dtype=np.uint32)
-    roll = merkle.make_extranonce_roll_batch(hdr80, prefix, suffix, 4, ())
-    mids, tails = roll(jnp.zeros(b, jnp.uint32), jnp.asarray(ens))
+    mids, tails = merkle.roll_batch_deduped(
+        *merkle.roll_template(hdr80, prefix, suffix, 4, ()),
+        np.zeros(b, np.uint32), ens)
     bases = rng.randint(0, 1 << 20, b).astype(np.uint32)
     valids = rng.randint(0, width + 1, b).astype(np.uint32)
     goffs = (np.arange(b, dtype=np.uint64) * width).astype(np.uint32)
@@ -1216,14 +1217,17 @@ def test_roll_batch_deduped_any_row_multiset(ens):
 
     rng = np.random.RandomState(16)
     prefix, suffix = rng.bytes(41), rng.bytes(60)
-    roll = merkle.make_extranonce_roll_batch(
+    layout, words = merkle.roll_template(
         chain.GENESIS_HEADER.pack(), prefix, suffix, 8, ()
     )
     en = np.asarray(ens, dtype=np.uint64)
     en_hi = (en >> np.uint64(32)).astype(np.uint32)
     en_lo = (en & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-    want_m, want_t = roll(jnp.asarray(en_hi), jnp.asarray(en_lo))
-    got_m, got_t = merkle.roll_batch_deduped(roll, en_hi, en_lo)
+    # every row rolled on its own, no dedup
+    want_m, want_t = merkle.extranonce_roll(
+        layout, *words, jnp.asarray(en_hi), jnp.asarray(en_lo),
+        jnp.arange(len(ens), dtype=jnp.int32))
+    got_m, got_t = merkle.roll_batch_deduped(layout, words, en_hi, en_lo)
     assert np.array_equal(np.asarray(want_m), np.asarray(got_m))
     assert np.array_equal(np.asarray(want_t), np.asarray(got_t))
 

@@ -45,9 +45,9 @@ def _rolled_rows(rng, b, width, ragged=True):
     ``(mids, tails, bases, valids, goffs)`` plus the extranonces that
     the hashlib reference re-rolls on the host."""
     ens = rng.randint(0, 1 << 32, b, dtype=np.uint32)
-    roll = merkle.make_extranonce_roll_batch(
-        _HDR80, _PREFIX, _SUFFIX, 4, _BRANCH)
-    mids, tails = roll(jnp.zeros(b, jnp.uint32), jnp.asarray(ens))
+    mids, tails = merkle.roll_batch_deduped(
+        *merkle.roll_template(_HDR80, _PREFIX, _SUFFIX, 4, _BRANCH),
+        np.zeros(b, np.uint32), ens)
     bases = rng.randint(0, 1 << 20, b).astype(np.uint32)
     if ragged:
         valids = np.where(
@@ -176,6 +176,15 @@ def test_batched_sweep_matches_hashlib_on_candidate_bearing_window():
 # the roll dedup: gathered uniques ≡ rolling every row
 # ---------------------------------------------------------------------------
 
+def _roll_each_row(template, en_hi, en_lo):
+    """The roll of every row on its own: no dedup, row i forked from
+    extranonce i."""
+    layout, words = template
+    return merkle.extranonce_roll(
+        layout, *words, jnp.asarray(en_hi), jnp.asarray(en_lo),
+        jnp.arange(len(en_lo), dtype=jnp.int32))
+
+
 def test_roll_batch_deduped_bit_equal():
     """roll_batch_deduped ≡ the plain batched roll — duplicate-heavy,
     all-unique, and all-identical extranonce row sets."""
@@ -183,7 +192,7 @@ def test_roll_batch_deduped_bit_equal():
     prefix, suffix = rng.bytes(41), rng.bytes(60)
     branch = (rng.bytes(32), rng.bytes(32))
     hdr80 = chain.GENESIS_HEADER.pack()
-    roll = merkle.make_extranonce_roll_batch(hdr80, prefix, suffix, 4, branch)
+    template = merkle.roll_template(hdr80, prefix, suffix, 4, branch)
     cases = [
         np.array([5, 5, 5, 6, 6, 7, 5, 6], np.uint32),   # dup-heavy
         np.arange(8, dtype=np.uint32),                    # all unique
@@ -192,8 +201,8 @@ def test_roll_batch_deduped_bit_equal():
     ]
     for en_lo in cases:
         en_hi = np.zeros_like(en_lo)
-        want_m, want_t = roll(jnp.asarray(en_hi), jnp.asarray(en_lo))
-        got_m, got_t = merkle.roll_batch_deduped(roll, en_hi, en_lo)
+        want_m, want_t = _roll_each_row(template, en_hi, en_lo)
+        got_m, got_t = merkle.roll_batch_deduped(*template, en_hi, en_lo)
         assert np.array_equal(np.asarray(want_m), np.asarray(got_m))
         assert np.array_equal(np.asarray(want_t), np.asarray(got_t))
 
@@ -204,11 +213,11 @@ def test_roll_batch_deduped_wide_extranonce():
     rng = np.random.RandomState(SEED + 4)
     prefix, suffix = rng.bytes(41), rng.bytes(60)
     hdr80 = chain.GENESIS_HEADER.pack()
-    roll = merkle.make_extranonce_roll_batch(hdr80, prefix, suffix, 8, ())
+    template = merkle.roll_template(hdr80, prefix, suffix, 8, ())
     en_hi = np.array([0, 1, 0, 1], np.uint32)
     en_lo = np.array([7, 7, 7, 7], np.uint32)
-    want_m, want_t = roll(jnp.asarray(en_hi), jnp.asarray(en_lo))
-    got_m, got_t = merkle.roll_batch_deduped(roll, en_hi, en_lo)
+    want_m, want_t = _roll_each_row(template, en_hi, en_lo)
+    got_m, got_t = merkle.roll_batch_deduped(*template, en_hi, en_lo)
     assert np.array_equal(np.asarray(want_m), np.asarray(got_m))
     assert np.array_equal(np.asarray(want_t), np.asarray(got_t))
     assert not np.array_equal(np.asarray(want_m)[0], np.asarray(want_m)[1])

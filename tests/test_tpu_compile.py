@@ -94,6 +94,24 @@ def test_rolled_batch_kernel_compiles(compiled_for, rows):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("uniques", [1, 2])
+def test_extranonce_roll_compiles_for_the_stratum_layout(compiled_for, uniques):
+    """The roll of a 70-byte coinbase prefix, an 87-byte suffix, a
+    4-byte extranonce2 and a 12-level branch, forked to a window's 8
+    rows: the template's words are operands, so this one program serves
+    every template of the layout."""
+    from tpuminter.ops import merkle
+
+    layout, words = merkle.roll_template(
+        bytes(80), bytes(70), bytes(87), 4, [bytes(32)] * 12)
+    text = compiled_for(
+        lambda *args: merkle.extranonce_roll(layout, *args),
+        *((w.shape, U32) for w in words),
+        ((uniques,), U32), ((uniques,), U32), ((8,), jnp.int32),
+    )
+    assert "ENTRY" in text
+
+
 def test_splitmix_kernel_compiles(compiled_for):
     n = 1 << 20
     text = compiled_for(

@@ -394,7 +394,7 @@ def rolled_header(
     """The header actually mined at a given extranonce: ``header80``'s
     merkle-root field replaced by the root recomputed from the mutated
     coinbase (BASELINE.json:9-10's roll, host reference semantics; the
-    device equivalent is ``tpuminter.ops.merkle.make_extranonce_roll_batch``,
+    device equivalent is ``tpuminter.ops.merkle.extranonce_roll``,
     one row per extranonce).
     """
     root = coinbase.merkle_root(extranonce, branch)

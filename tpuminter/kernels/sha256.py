@@ -441,7 +441,7 @@ def _cand_hdr_kernel(n_tiles, tiles_per_step, n_valid, mask_tail,
     """Early-reject sweep over a header whose midstate and variable tail
     words arrive in SMEM at *runtime* instead of being baked at trace
     time: the consumer of the on-device extranonce roll
-    (``ops.merkle.make_extranonce_roll_batch`` → this kernel, zero host
+    (``ops.merkle.extranonce_roll`` → this kernel, zero host
     round-trips per roll, BASELINE.json:9-10) — and, as a bonus, a
     single compiled kernel that serves EVERY header-mining job (no
     ~20-40 s per-job compile).
@@ -516,7 +516,7 @@ def pallas_search_candidates_hdr(
     """Dynamic-header twin of :func:`pallas_search_candidates`: the
     header midstate (8 u32) and variable tail words (merkle word 7,
     time, bits) are runtime device values — pass one row of
-    ``ops.merkle.make_extranonce_roll_batch``'s outputs straight in;
+    ``ops.merkle.extranonce_roll``'s outputs straight in;
     they never visit the host. Same return contract: ``(found, first_off)``."""
     if not 1 <= n <= 1 << 30:
         raise ValueError("n must be in [1, 2^30] (int32 offset domain)")
@@ -550,7 +550,7 @@ def _cand_hdr_batch_kernel(n_tiles, tiles_per_step,
     """One grid step = one roll ROW of the batched sweep: identical
     candidate test to ``_cand_hdr_kernel``, but the row's midstate, tail
     words, nonce base AND valid count all arrive per-row at runtime
-    (row ``pl.program_id(0)`` of the whole ``make_extranonce_roll_batch``
+    (row ``pl.program_id(0)`` of the whole ``extranonce_roll``
     output, which sits in SMEM unblocked: Mosaic refuses ``(1, 8)``
     SMEM blocks of an ``(B, 8)`` array). The valid count is dynamic because rows are the ragged
     ``chain.rolled_tiles`` of an arbitrary global window — the loop
@@ -631,7 +631,7 @@ def pallas_search_candidates_hdr_batch(
     """Batched twin of :func:`pallas_search_candidates_hdr`: a grid over
     ``B`` roll rows, each sweeping up to ``width`` nonces of ITS OWN
     dynamic header — ``(B, 8)`` midstates, ``(B, 3)`` tail batches
-    (``ops.merkle.make_extranonce_roll_batch`` outputs, straight from
+    (``ops.merkle.extranonce_roll`` outputs, straight from
     device memory), ``(B,)`` per-row nonce bases and valid counts. One
     dispatch sweeps ``B·width`` global indices; segment boundaries cost
     nothing because they are just row edges of the same launch.

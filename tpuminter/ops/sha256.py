@@ -39,10 +39,13 @@ from tpuminter.chain import SHA256_H0, SHA256_K, sha256_compress
 
 __all__ = [
     "compress",
+    "HoleLayout",
     "NonceTemplate",
+    "plan_hole",
     "toy_template",
     "header_template",
     "sha256_batch",
+    "sha256_batch_dyn",
     "double_sha256_header_batch",
     "HEADER_NONCE_POSITIONS",
     "HEADER_TAIL_PAD",
@@ -76,7 +79,7 @@ def _round_unroll() -> bool:
     compile in ~3 s and run ~30x faster than the scanned form (the
     shared-schedule sweep, PERF.md §Round 14), but stacking their output
     into a trailing-axis array or chaining ~10 compressions straight-line
-    (the roll: ~40 s/job; the tracking step: 15-42 s) blows the compile
+    (the roll: ~40 s a program; the tracking step: 15-42 s) blows the compile
     budget. The scanned default stays right for this general-purpose
     entry point, which callers embed many-at-a-time; the sweep-shaped
     winners opt into the unrolled symbolic form explicitly
@@ -165,17 +168,33 @@ def _compress_scanned(state: jnp.ndarray, block: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 @dataclass(frozen=True)
+class HoleLayout:
+    """Where a nonce-shaped hole sits in a padded SHA-256 message: all
+    that a jitted hash of the message has to know while it is traced.
+
+    ``n_tail_blocks``: the blocks from the one holding the hole's first
+    byte to the end of the padding. ``positions``: one entry per nonce
+    byte — ``(block, word, word_shift, nonce_shift)`` meaning
+    ``tail[block, word] |= ((nonce >> nonce_shift) & 0xFF) << word_shift``.
+    ``double``: apply a second SHA-256 over the 32-byte digest (Bitcoin).
+    It depends on the message's length and the hole's offset, never on
+    the bytes, so messages of one layout share one compiled program.
+    """
+
+    n_tail_blocks: int
+    positions: Tuple[Tuple[int, int, int, int], ...]
+    double: bool
+
+
+@dataclass(frozen=True)
 class NonceTemplate:
     """A padded SHA-256 message with a nonce-shaped hole.
 
     ``midstate``: state after the constant prefix blocks (8 u32).
     ``tail``: the remaining block(s), nonce bytes zeroed ((n, 16) u32).
-    ``positions``: one entry per nonce byte —
-    ``(block, word, word_shift, nonce_shift)`` meaning
-    ``tail[block, word] |= ((nonce >> nonce_shift) & 0xFF) << word_shift``.
+    ``positions`` and ``double``: the :class:`HoleLayout`'s.
     All entries are Python ints: jitted code closes over them as static
     constants (this dataclass is hashable ⇒ usable as a jit cache key).
-    ``double``: apply a second SHA-256 over the 32-byte digest (Bitcoin).
     """
 
     midstate: Tuple[int, ...]
@@ -186,6 +205,10 @@ class NonceTemplate:
     @property
     def n_tail_blocks(self) -> int:
         return len(self.tail)
+
+    @property
+    def layout(self) -> HoleLayout:
+        return HoleLayout(self.n_tail_blocks, self.positions, self.double)
 
     def tail_array(self) -> np.ndarray:
         return np.array(self.tail, dtype=np.uint32)
@@ -200,6 +223,39 @@ def _pad(message_len: int) -> bytes:
     return pad + struct.pack(">Q", message_len * 8)
 
 
+def plan_hole(
+    message_with_hole: bytes,
+    hole_offset: int,
+    byte_map: Sequence[Tuple[int, int]],
+    *,
+    double: bool,
+) -> Tuple[HoleLayout, np.ndarray, np.ndarray]:
+    """Split a message with a hole into its layout and its words:
+    ``(layout, midstate (8,) u32, tail (n_tail_blocks, 16) u32)``.
+    ``byte_map[j] = (offset_delta, nonce_shift)`` puts ``(nonce >>
+    nonce_shift) & 0xFF`` at ``hole_offset + offset_delta``. The whole
+    blocks before the hole are compressed here, on the host, into the
+    midstate; the tail holds the message from the hole's block on, the
+    hole zeroed."""
+    padded = message_with_hole + _pad(len(message_with_hole))
+    assert len(padded) % 64 == 0
+    first_hole_block = min(hole_offset + d for d, _ in byte_map) // 64
+    state = tuple(SHA256_H0)
+    for b in range(first_hole_block):
+        state = sha256_compress(state, padded[b * 64 : (b + 1) * 64])
+    tail = np.frombuffer(padded[first_hole_block * 64 :], dtype=">u4")
+    positions = []
+    for offset_delta, nonce_shift in byte_map:
+        off = hole_offset + offset_delta - first_hole_block * 64
+        positions.append((off // 64, (off % 64) // 4, 24 - 8 * (off % 4), nonce_shift))
+    layout = HoleLayout(len(tail) // 16, tuple(positions), double)
+    return (
+        layout,
+        np.array(state, dtype=np.uint32),
+        tail.astype(np.uint32).reshape(-1, 16),
+    )
+
+
 def _build_template(
     message_with_hole: bytes,
     hole_offset: int,
@@ -207,25 +263,16 @@ def _build_template(
     *,
     double: bool,
 ) -> NonceTemplate:
-    """Plan a template: ``byte_map[j] = (offset_delta, nonce_shift)`` puts
-    ``(nonce >> nonce_shift) & 0xFF`` at ``hole_offset + offset_delta``."""
-    padded = message_with_hole + _pad(len(message_with_hole))
-    assert len(padded) % 64 == 0
-    first_hole_block = min(hole_offset + d for d, _ in byte_map) // 64
-    state = tuple(SHA256_H0)
-    for b in range(first_hole_block):
-        state = sha256_compress(state, padded[b * 64 : (b + 1) * 64])
-    tail_bytes = padded[first_hole_block * 64 :]
-    tail = tuple(
-        struct.unpack(">16I", tail_bytes[b * 64 : (b + 1) * 64])
-        for b in range(len(tail_bytes) // 64)
+    """:func:`plan_hole` with its words as Python ints, for the kernels
+    that bake a template into their program."""
+    layout, midstate, tail = plan_hole(
+        message_with_hole, hole_offset, byte_map, double=double
     )
-    positions = []
-    for offset_delta, nonce_shift in byte_map:
-        off = hole_offset + offset_delta - first_hole_block * 64
-        positions.append((off // 64, (off % 64) // 4, 24 - 8 * (off % 4), nonce_shift))
     return NonceTemplate(
-        midstate=state, tail=tail, positions=tuple(positions), double=double
+        midstate=tuple(midstate.tolist()),
+        tail=tuple(map(tuple, tail.tolist())),
+        positions=layout.positions,
+        double=double,
     )
 
 
@@ -255,15 +302,14 @@ def header_template(header80: bytes) -> NonceTemplate:
 # ---------------------------------------------------------------------------
 
 def _inject_nonces(
-    template: NonceTemplate, nonce_hi: jnp.ndarray, nonce_lo: jnp.ndarray
+    layout: HoleLayout, tail: jnp.ndarray, nonce_hi: jnp.ndarray,
+    nonce_lo: jnp.ndarray,
 ) -> jnp.ndarray:
-    """Broadcast the tail template over the batch and OR in the nonce
-    bytes at their static positions → ``(N, n_blocks, 16) u32``."""
+    """Broadcast the tail words over the batch and OR in the nonce
+    bytes at the layout's static positions → ``(N, n_blocks, 16) u32``."""
     n = nonce_lo.shape[0]
-    tail = jnp.broadcast_to(
-        jnp.asarray(template.tail_array()), (n,) + (template.n_tail_blocks, 16)
-    )
-    for block, word, word_shift, nonce_shift in template.positions:
+    tail = jnp.broadcast_to(jnp.asarray(tail), (n, layout.n_tail_blocks, 16))
+    for block, word, word_shift, nonce_shift in layout.positions:
         src = nonce_hi if nonce_shift >= 32 else nonce_lo
         shift = nonce_shift - 32 if nonce_shift >= 32 else nonce_shift
         byte = (src >> np.uint32(shift)) & np.uint32(0xFF)
@@ -277,14 +323,34 @@ def sha256_batch(
     """Digests for a batch of nonces: ``(N,) u32 × 2 → (N, 8) u32``
     (digest as big-endian u32 words, i.e. ``struct.unpack('>8I', digest)``).
 
-    Applies the template's second hash when ``template.double``.
+    Applies the template's second hash when ``template.double``. The
+    template's words are constants of the traced program.
     """
+    return sha256_batch_dyn(
+        template.layout, template.midstate_array(), template.tail_array(),
+        nonce_hi, nonce_lo,
+    )
+
+
+def sha256_batch_dyn(
+    layout: HoleLayout,
+    midstate: jnp.ndarray,
+    tail: jnp.ndarray,
+    nonce_hi: jnp.ndarray,
+    nonce_lo: jnp.ndarray,
+    unroll: bool | None = None,
+) -> jnp.ndarray:
+    """:func:`sha256_batch` with the message's words as operands: only
+    ``layout`` is static, so one compiled program hashes every message
+    of that layout (the extranonce roll's coinbase, ``ops.merkle``).
+    ``midstate (8,)`` and ``tail (n_tail_blocks, 16)`` are
+    :func:`plan_hole`'s words; ``unroll`` is :func:`compress`'s."""
     n = nonce_lo.shape[0]
-    tail = _inject_nonces(template, nonce_hi, nonce_lo)
-    state = jnp.broadcast_to(jnp.asarray(template.midstate_array()), (n, 8))
-    for b in range(template.n_tail_blocks):
-        state = compress(state, tail[:, b, :])
-    if template.double:
+    blocks = _inject_nonces(layout, tail, nonce_hi, nonce_lo)
+    state = jnp.broadcast_to(jnp.asarray(midstate), (n, 8))
+    for b in range(layout.n_tail_blocks):
+        state = compress(state, blocks[:, b, :], unroll)
+    if layout.double:
         # second message: 32-byte digest ‖ 0x80 ‖ zeros ‖ len(256 bits)
         block2 = jnp.concatenate(
             [
@@ -300,7 +366,7 @@ def sha256_batch(
             ],
             axis=-1,
         )
-        state = compress(jnp.broadcast_to(jnp.asarray(_H0), (n, 8)), block2)
+        state = compress(jnp.broadcast_to(jnp.asarray(_H0), (n, 8)), block2, unroll)
     return state
 
 
@@ -342,10 +408,10 @@ def header_digest_dyn(
     (B, 3) tails + (B, N) nonces → (B, N, 8)``: row ``i``'s nonces are
     hashed under row ``i``'s header. The batched form is the jnp engine
     of the batched rolled sweep (``tpuminter.rolled``): one dispatch
-    sweeps every row of a ``make_extranonce_roll_batch`` output.
+    sweeps every row of an ``extranonce_roll`` output.
 
     This is the hash the on-device extranonce roll feeds
-    (``ops.merkle.make_extranonce_roll_batch`` produces exactly this
+    (``ops.merkle.extranonce_roll`` produces exactly this
     ``(midstate, tail_words)`` pair for each extranonce, BASELINE.json:
     9-10): one compiled program serves every extranonce — and every
     header-mining job — because nothing job-specific is baked in.

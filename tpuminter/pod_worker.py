@@ -17,7 +17,7 @@ Dialect routing:
   in-kernel early exit per chip and at most ``n_slabs`` ICI rounds —
   the host only verifies the ~1-per-2^32 candidates. Rolled jobs use
   the dynamic-header sweep (one compile for every extranonce) with the
-  roll itself on device (``ops.merkle.make_extranonce_roll_batch``).
+  roll itself on device (``ops.merkle.extranonce_roll``).
 - **MIN** runs the fused Pallas toy kernel per chip under ``shard_map``
   (``parallel.build_min_sweep_pallas`` — the single-chip TpuMiner's
   engine at pod scale) with the argmin fold over ICI; the CPU mesh (CI)
@@ -356,11 +356,13 @@ class PodMiner(Miner):
         ``CandidateSearch`` over global indices whose windows are
         ``parallel.build_rolled_sweep`` dispatches — device-major
         interleaved roll rows with stripe-synchronous ICI early exit —
-        fed by one batched roll call per window. The pod stops
-        re-entering host orchestration 2^ext_bits times per chunk."""
+        fed by one batched roll call per window (``rolled.job_roll``:
+        the template is an operand, one compiled roll per layout). The
+        pod stops re-entering host orchestration 2^ext_bits times per
+        chunk. A window is planned up to the chunk's ``upper``; rows
+        past it stay padding (valid 0), which the kernel skips."""
         assert req.header is not None and req.target is not None
         from tpuminter import rolled
-        from tpuminter.ops import merkle
         from tpuminter.parallel import build_rolled_sweep
 
         rolled._check_roll_batch(self.roll_batch)
@@ -380,10 +382,7 @@ class PodMiner(Miner):
                 cand_bits=self._cand_bits,
             )
         sweep_prog = self._rolled_sweeps[key]
-        roll = merkle.make_extranonce_roll_batch(
-            req.header, req.coinbase_prefix, req.coinbase_suffix,
-            req.extranonce_size, req.branch,
-        )
+        roll = rolled.job_roll(req)
         cap = _biased_cap(req.target)
         hard_end = (1 << rolled.span_bits(req)) - 1
         n_dev = self.n_dev
@@ -391,12 +390,10 @@ class PodMiner(Miner):
         def sweep(start: int, n: int, after):
             # does not chain: ``after`` is not read
             plan = rolled.plan_tiles(
-                start, n, req.nonce_bits, width, rows, hard_end,
-                interleave=n_dev,
+                start, min(n, req.upper - start + 1), req.nonce_bits,
+                width, rows, hard_end, interleave=n_dev,
             )
-            mids, tails = roll(
-                jnp.asarray(plan.en_hi), jnp.asarray(plan.en_lo)
-            )
+            mids, tails = roll(plan)
             found, first, _ = sweep_prog(
                 mids, tails, jnp.asarray(plan.bases),
                 jnp.asarray(plan.valids), jnp.asarray(plan.goffs), cap,

@@ -15,9 +15,12 @@ module makes the rolled sweep batch- and pipeline-native end to end:
   two), so a window needs at most ``roll_batch + 2`` rows
   (:func:`plan_tiles` pads to exactly that, keeping every dispatch the
   same compiled shape).
-- **One roll call per window.** ``ops.merkle.make_extranonce_roll_batch``
+- **One roll call per window.** ``ops.merkle.extranonce_roll``
   produces every row's ``(midstate, tail_words)`` in ONE device call;
-  the outputs never visit the host.
+  the outputs never visit the host. The job's template is an operand
+  of that program, compiled once per coinbase/branch layout, so a
+  Stratum job with a fresh template every notify costs no compile
+  (:func:`job_roll`).
 - **One sweep call per window.** The per-row-midstate candidate sweep
   (``kernels.pallas_search_candidates_hdr_batch`` on TPU, its jnp
   mirror here on the CPU mesh) grids over (roll-row × nonce-slab), so
@@ -52,11 +55,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpuminter import chain
+from tpuminter.ops import merkle
 from tpuminter.ops import sha256 as ops
 from tpuminter.protocol import MIN_UNTRACKED, Request, Result
 from tpuminter.search import (
     CandidateSearch, pipeline_spans, pull, resolve_handle,
 )
+from tpuminter.spans import ROLL, span
 
 __all__ = [
     "plan_tiles",
@@ -64,6 +69,7 @@ __all__ = [
     "tile_width",
     "span_bits",
     "rolled_verifier",
+    "job_roll",
     "mine_rolled_fast",
     "mine_rolled_tracking",
     "ProgressFn",
@@ -200,6 +206,32 @@ def rolled_verifier(req: Request):
         return h <= req.target, h
 
     return verify
+
+
+def job_roll(req: Request) -> Callable[[TilePlan], Tuple[jnp.ndarray, jnp.ndarray]]:
+    """``roll(plan) -> (midstates, tails)`` for the rows of a plan of one
+    chunk of a rolled job: the deduped batched roll
+    (:func:`tpuminter.ops.merkle.roll_batch_deduped`) through the one
+    program of the template's layout. Every call is a ``tpuminter.roll``
+    span with the chunk's ids and the plan's valid indices; the
+    template's words are built and sent to the device inside the first
+    call, so that span holds what switching to a new template costs.
+    Shared by every batched rolled path (here and ``pod_worker``)."""
+    template = None
+
+    def call(plan: TilePlan):
+        nonlocal template
+        nonces = int(plan.valids.sum())
+        with span(ROLL, job=req.job_id, chunk=req.chunk_id, nonces=nonces):
+            if template is None:
+                layout, words = merkle.roll_template(
+                    req.header, req.coinbase_prefix, req.coinbase_suffix,
+                    req.extranonce_size, req.branch,
+                )
+                template = layout, tuple(jnp.asarray(w) for w in words)
+            return merkle.roll_batch_deduped(*template, plan.en_hi, plan.en_lo)
+
+    return call
 
 
 def report_search_progress(search: CandidateSearch, fallback_nonce: int,
@@ -383,8 +415,11 @@ def mine_rolled_fast(
     hashes through the truncated unrolled second compression
     (:func:`_jnp_candidate_ok_sched`, or the kernel's ``sym.prepare_hdr``
     hoist on the Pallas engine) and the batched roll dedupes identical
-    extranonce rows before dispatch
-    (:func:`tpuminter.ops.merkle.roll_batch_deduped`).
+    extranonce rows before dispatch (:func:`job_roll`). A window is
+    planned up to the chunk's ``upper`` only: the rows past it stay
+    padding (valid 0), which the kernel's row loop skips, so a chunk
+    shorter than the window costs the device its own indices and no
+    more.
 
     ``progress`` (:data:`ProgressFn`) receives the settled global-index
     high-water after each resolved window — the roll-budget beacon feed.
@@ -392,7 +427,6 @@ def mine_rolled_fast(
     assert req.rolled and req.header is not None and req.target is not None
     _check_roll_batch(roll_batch)
     engine = _resolve_engine(engine)
-    from tpuminter.ops import merkle
 
     hw1_cap = jnp.uint32(int(ops.target_to_words(req.target)[1]))
     width = tile_width(req.nonce_bits, slab)
@@ -401,18 +435,16 @@ def mine_rolled_fast(
     if window >= 1 << 32:
         raise ValueError("roll_batch × width must stay below 2^32")
     hard_end = (1 << span_bits(req)) - 1
-    roll = merkle.make_extranonce_roll_batch(
-        req.header, req.coinbase_prefix, req.coinbase_suffix,
-        req.extranonce_size, req.branch,
-    )
+    roll = job_roll(req)
 
     def sweep(start: int, n: int, after):
         # the batched kernel does not chain: ``after`` is not read
+        n = min(n, req.upper - start + 1)
         plan = lean_plan(
             plan_tiles(start, n, req.nonce_bits, width, rows, hard_end),
             roll_batch,
         )
-        mids, tails = merkle.roll_batch_deduped(roll, plan.en_hi, plan.en_lo)
+        mids, tails = roll(plan)
         args = (
             mids, tails, jnp.asarray(plan.bases), jnp.asarray(plan.valids),
             jnp.asarray(plan.goffs), hw1_cap,
@@ -503,9 +535,8 @@ def mine_rolled_tracking(
     toy-easy-target correctness path plus JaxMiner's production rolled
     path. Pinned to brute force in tests/test_extranonce.py.
 
-    The roll goes through the extranonce dedup
-    (:func:`tpuminter.ops.merkle.roll_batch_deduped`); the tracking
-    step itself keeps the scanned full-digest compress. Sharing the
+    The roll goes through the extranonce dedup (:func:`job_roll`); the
+    tracking step itself keeps the scanned full-digest compress. Sharing the
     unrolled schedule inside the full-digest + lexicographic-min fold
     was measured and REJECTED — every fold structure tried either lost
     outright or paid a 15-42 s compile per width (PERF.md §Round 14);
@@ -514,16 +545,12 @@ def mine_rolled_tracking(
     """
     assert req.rolled and req.target is not None
     _check_roll_batch(roll_batch)
-    from tpuminter.ops import merkle
 
     width = tile_width(req.nonce_bits, width_cap)
     rows = roll_batch + 2
     window = roll_batch * width
     hard_end = (1 << span_bits(req)) - 1
-    roll = merkle.make_extranonce_roll_batch(
-        req.header, req.coinbase_prefix, req.coinbase_suffix,
-        req.extranonce_size, req.branch,
-    )
+    roll = job_roll(req)
     target_words = jnp.asarray(ops.target_to_words(req.target))
 
     def dispatch(start: int):
@@ -534,7 +561,7 @@ def mine_rolled_tracking(
             plan_tiles(start, n, req.nonce_bits, width, rows, hard_end),
             roll_batch,
         )
-        mids, tails = merkle.roll_batch_deduped(roll, plan.en_hi, plan.en_lo)
+        mids, tails = roll(plan)
         return _tracking_step(
             mids, tails, jnp.asarray(plan.bases), jnp.asarray(plan.valids),
             jnp.asarray(plan.goffs), target_words, width,

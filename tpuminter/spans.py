@@ -22,6 +22,10 @@ The vocabulary, fixed so that trace readers and tests share it:
   ``job``, ``chunk`` (``miner_proc``).
 - :data:`CANCEL`: the miner child abandons a chunk that is no longer
   wanted; args ``job``, ``chunk`` (``miner_proc``).
+- :data:`ROLL`: one extranonce roll of a rolled sweep, handed to the
+  device; the first of a chunk also builds the template's words, so it
+  holds the cost of a new template; args ``job``, ``chunk`` and
+  ``nonces``, the indices the sweep it feeds covers (``rolled``).
 """
 
 from __future__ import annotations
@@ -29,13 +33,16 @@ from __future__ import annotations
 import contextlib
 import sys
 
-__all__ = ["AWAIT_CHUNK", "CANCEL", "DISPATCH", "RESOLVE", "WINNER", "span"]
+__all__ = [
+    "AWAIT_CHUNK", "CANCEL", "DISPATCH", "RESOLVE", "ROLL", "WINNER", "span",
+]
 
 AWAIT_CHUNK = "tpuminter.await_chunk"
 DISPATCH = "tpuminter.dispatch"
 RESOLVE = "tpuminter.resolve"
 WINNER = "tpuminter.winner"
 CANCEL = "tpuminter.cancel"
+ROLL = "tpuminter.roll"
 
 _OFF = contextlib.nullcontext()
 
