@@ -390,3 +390,65 @@ def test_pod_miner_on_four_chips_answers_genesis_jobs(pod4, below):
     result = _drain(pod4.mine(req))
     assert result.found and (result.nonce, result.hash_value) == want
     assert result.searched >= below + 1
+
+
+@pytest.fixture(scope="module")
+def striped_pod(mesh4):
+    """Four-chip PodMiners by ``slab_per_device`` (two slabs a chip a
+    call), each with its TARGET sweep built by a genesis job."""
+    built = {}
+
+    def get(slab):
+        if slab not in built:
+            miner = PodMiner(
+                mesh=mesh4, slab_per_device=slab, n_slabs=2, kernel="jnp"
+            )
+            req = Request(
+                job_id=40, mode=PowMode.TARGET, lower=GEN.nonce - 100,
+                upper=GEN.nonce + 100, header=GEN.pack(), target=TARGET,
+            )
+            result = _drain(miner.mine(req))
+            assert result.found and result.nonce == GEN.nonce
+            built[slab] = miner
+        return built[slab]
+
+    return get
+
+
+@pytest.mark.parametrize("slab, stripe, k, r", [
+    (256, 256, 0, 37),    # chip 0's piece of the job's first slab
+    (256, 256, 1, 200),   # chip 3's piece of the second slab
+    (256, 256, 3, 130),   # chip 2's piece of the fourth slab
+    (250, 1000, 1, 300),  # 250 does not split over 4 chips: whole slabs
+])
+def test_pod_miner_sweeps_one_slab_a_stripe(striped_pod, slab, stripe, k, r):
+    """The TARGET sweep the miner built stripes one slab across the
+    four chips, ``slab / 4`` nonces a chip: a winner ``k`` slabs and
+    ``r`` nonces past the start stops the call after stripe ``k``, with
+    its exact offset. A slab that does not split evenly keeps one whole
+    slab a chip, so a stripe is four slabs."""
+    sweep = striped_pod(slab)._sweep_static
+    offset = k * stripe + r
+    found, first, stripes = sweep(
+        jnp.uint32(GEN.nonce - offset), _biased_cap(TARGET)
+    )
+    assert (int(found), int(first), int(stripes)) == (1, offset, k + 1)
+
+
+@pytest.mark.parametrize("slab, stripes", [(256, 8), (250, 2)])
+def test_pod_miner_stripe_plan_covers_the_dispatched_span(
+        striped_pod, slab, stripes):
+    """Whatever the stripes, a call still covers ``n_dev × n_slabs ×
+    slab_per_device`` nonces, the span the search dispatches and the
+    coordinator is told: the span's last nonce is swept in its last
+    stripe, and a window that ends just below the winner runs every
+    stripe and finds nothing."""
+    miner = striped_pod(slab)
+    span = 4 * 2 * slab
+    assert miner.pod_span == miner.span == span
+    cap = _biased_cap(TARGET)
+    found, first, ran = miner._sweep_static(
+        jnp.uint32(GEN.nonce - (span - 1)), cap)
+    assert (int(found), int(first), int(ran)) == (1, span - 1, stripes)
+    found, _, ran = miner._sweep_static(jnp.uint32(GEN.nonce - span), cap)
+    assert (int(found), int(ran)) == (0, stripes)

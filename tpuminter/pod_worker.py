@@ -14,8 +14,13 @@ Dialect routing:
 - **TARGET** (plain and extranonce-rolled) is the production path:
   ``search.CandidateSearch`` pipelines pod-wide sweeps ``depth`` deep,
   each covering ``n_dev × n_slabs × slab_per_device`` nonces with
-  in-kernel early exit per chip and at most ``n_slabs`` ICI rounds —
-  the host only verifies the ~1-per-2^32 candidates. Rolled jobs use
+  in-kernel early exit per chip — the host only verifies the
+  ~1-per-2^32 candidates. A plain job's stripe is one slab split over
+  the chips, ``slab_per_device / n_dev`` contiguous nonces each, so a
+  winner in a call's first slab stops it once every chip has swept its
+  piece, not a whole slab; a call makes up to ``n_slabs × n_dev`` ICI
+  rounds. A slab that does not split evenly keeps one slab a chip a
+  stripe. Rolled jobs use
   the dynamic-header sweep (one compile for every extranonce) with the
   roll itself on device (``ops.merkle.extranonce_roll``).
 - **MIN** runs the fused Pallas toy kernel per chip under ``shard_map``
@@ -99,8 +104,10 @@ def follower_loop(miner: "PodMiner") -> None:
             except StopIteration:
                 break
 
-#: defaults sized for v5e chips (cf. tpu_worker.DEFAULT_SLAB): 2^27
-#: nonces ≈ 130 ms per chip per stripe, 4 stripes per pod call
+#: defaults sized for v5e chips (cf. tpu_worker.DEFAULT_SLAB): a slab
+#: of 2^27 nonces, 4 slabs a chip per pod call (2^31 nonces on four
+#: chips). A TARGET stripe is one slab across the pod, 2^25 nonces ≈
+#: 33 ms a chip on four chips, so a call makes 16 ICI rounds
 DEFAULT_SLAB_PER_DEVICE = 1 << 27
 DEFAULT_N_SLABS = 4
 
@@ -145,7 +152,15 @@ class PodMiner(Miner):
         self.n_dev = int(self.mesh.devices.size)
         self.slab_per_device = slab_per_device
         self.n_slabs = n_slabs
-        self.pod_span = self.n_dev * n_slabs * slab_per_device
+        #: the TARGET sweep's layout (module docstring): each chip's
+        #: piece of a stripe and the stripes a call, one slab a stripe
+        #: where the slab splits evenly over the chips
+        if slab_per_device % self.n_dev == 0:
+            self.stripe_slab = slab_per_device // self.n_dev
+            self.stripes = n_slabs * self.n_dev
+        else:
+            self.stripe_slab, self.stripes = slab_per_device, n_slabs
+        self.pod_span = self.n_dev * self.stripes * self.stripe_slab
         if self.pod_span > 1 << 32:
             raise ValueError(
                 "pod span exceeds the 32-bit nonce space; shrink "
@@ -328,8 +343,8 @@ class PodMiner(Miner):
             self._template = template
             self._sweep_static = build_candidate_sweep(
                 self.mesh, template,
-                slab_per_device=self.slab_per_device,
-                n_slabs=self.n_slabs, tiles_per_step=self.tiles_per_step,
+                slab_per_device=self.stripe_slab,
+                n_slabs=self.stripes, tiles_per_step=self.tiles_per_step,
                 kernel=self.kernel,
             )
         cap = _biased_cap(req.target)
